@@ -231,7 +231,10 @@ def main(argv=None) -> int:
     from repro.core.engine import EngineConfig, PipeServeEngine
     from repro.data.workloads import sample_mixed, sample_requests
     from repro.distributed.sharding import unzip_params
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import build_model
+
+    enable_compile_cache()
 
     n_suite = 4 if args.reduced else 12
     n_mixed = 2 if args.reduced else 5          # per suite -> 8 / 20 requests

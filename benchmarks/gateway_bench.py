@@ -222,6 +222,9 @@ def main(argv=None) -> Dict[str, Any]:
     from repro.api import ServeConfig, StreamServe
     from repro.gateway import GatewayThread
     from repro.gateway.client import http_request
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     max_new = args.max_new or (4 if args.reduced else 8)
     per_stage = args.requests_per_stage or (24 if args.reduced else 80)

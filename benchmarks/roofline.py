@@ -23,7 +23,7 @@ from typing import Dict, List, Optional
 
 from repro.configs import ASSIGNED, get_config
 from repro.configs.base import SHAPES
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.serving.cost_model import TPU_V5E
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 DRYRUN = ROOT / "experiments" / "dryrun"
@@ -55,9 +55,9 @@ def analyze_cell(arch: str, shape_name: str, mesh: str = "single",
     if d["status"] != "ok":
         return {"arch": arch, "shape": shape_name, "status": d["status"],
                 "note": d.get("error", "")}
-    compute_s = d["flops_per_device"] / PEAK_FLOPS_BF16
-    memory_s = d["bytes_per_device"] / HBM_BW
-    collective_s = d["collectives"].get("total", 0) / ICI_BW
+    compute_s = d["flops_per_device"] / TPU_V5E.peak_flops
+    memory_s = d["bytes_per_device"] / TPU_V5E.hbm_bw
+    collective_s = d["collectives"].get("total", 0) / TPU_V5E.interconnect_bw
     terms = {"compute": compute_s, "memory": memory_s, "collective": collective_s}
     dominant = max(terms, key=terms.get)
     mf = model_flops_per_device(arch, shape_name)
@@ -65,7 +65,7 @@ def analyze_cell(arch: str, shape_name: str, mesh: str = "single",
     # roofline fraction: useful work per step over the time the dominant
     # term pins the step to (= achievable fraction of the compute roofline)
     step_s = max(terms.values())
-    roofline_frac = (mf / PEAK_FLOPS_BF16) / step_s if step_s > 0 else 0.0
+    roofline_frac = (mf / TPU_V5E.peak_flops) / step_s if step_s > 0 else 0.0
     return {
         "arch": arch,
         "shape": shape_name,

@@ -1,6 +1,6 @@
 """qwen3-1.7b — dense GQA decoder with qk_norm.
 
-[hf:Qwen/Qwen3-8B; hf]  28L d_model=2048 16H (GQA kv=8) d_ff=6144 vocab=151936.
+[hf:Qwen/Qwen3-1.7B; hf]  28L d_model=2048 16H (GQA kv=8) d_ff=6144 vocab=151936.
 """
 from repro.configs.base import ArchConfig
 
@@ -18,6 +18,6 @@ CONFIG = ArchConfig(
     rope_theta=1_000_000.0,
     tie_embeddings=True,
     scan_block=1,
-    source="hf:Qwen/Qwen3-8B",
+    source="hf:Qwen/Qwen3-1.7B",
     notes="qk_norm per-head RMSNorm on q/k; full attention -> long_500k skipped.",
 )

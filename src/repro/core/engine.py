@@ -193,11 +193,11 @@ def _tree_insert_pages(cache, chunk_blocks, row, page_ids, slot, seq_len):
     for name in cache["blocks"]:
         layer = dict(cache["blocks"][name])
         for kv in ("k", "v"):
-            pool = layer[kv]                      # (nb, n_pages, ps, K, D)
+            pool = layer[kv]                      # (nb, n_pages, K, ps, D)
             src = chunk_blocks[name][kv]          # (nb, R, L, K, D)
-            nb, _, ps, Kh, D = pool.shape
+            nb, _, Kh, ps, D = pool.shape
             rowdat = jax.lax.dynamic_index_in_dim(src, row, axis=1, keepdims=False)
-            pages = rowdat.reshape(nb, -1, ps, Kh, D)
+            pages = rowdat.reshape(nb, -1, ps, Kh, D).transpose(0, 1, 3, 2, 4)
             layer[kv] = pool.at[:, page_ids].set(
                 pages.astype(pool.dtype), mode="drop"
             )
@@ -1234,6 +1234,9 @@ class StreamPair:
                     }
                     logits, small = self.lane.prefill(batch)
                     self.lane.insert_rows(jnp.full((Bb,), drop_all, jnp.int32), small)
+                    # a max_len cache per admit row: kept alive it would sit
+                    # beside the next programs' buffers and raise peak HBM
+                    del small
                     sample(key, logits, econf.temperature)
                     prefill_batches.append(batch)
                     n += 1

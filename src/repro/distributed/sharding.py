@@ -20,7 +20,7 @@ from __future__ import annotations
 from typing import Any, Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
+from jax.sharding import AbstractMesh, Mesh, NamedSharding, PartitionSpec
 
 AxisName = Optional[str]
 LogicalAxes = Tuple[AxisName, ...]
@@ -215,7 +215,7 @@ def stack_axes(axes: LogicalAxes) -> LogicalAxes:
     return ("layer",) + tuple(axes)
 
 
-def constraint(x: jax.Array, axes: LogicalAxes, mesh: Optional[Mesh] = None, rules: Optional[Rules] = None) -> jax.Array:
+def constraint(x: jax.Array, axes: LogicalAxes, mesh: Optional[Mesh | AbstractMesh] = None, rules: Optional[Rules] = None) -> jax.Array:
     """with_sharding_constraint via logical axes (no-op without a mesh).
     Uses the ambient rules (``use_rules``) unless overridden."""
     mesh = mesh or _current_mesh()
@@ -225,7 +225,8 @@ def constraint(x: jax.Array, axes: LogicalAxes, mesh: Optional[Mesh] = None, rul
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
-def _current_mesh() -> Optional[Mesh]:
-    env = jax._src.mesh.thread_resources.env  # jax keeps the active `with mesh:`
-    mesh = env.physical_mesh
+def _current_mesh() -> Optional[AbstractMesh]:
+    """The mesh made active by ``jax.set_mesh`` (readable inside jit), or
+    None outside any mesh — every mesh-dependent path is then a no-op."""
+    mesh = jax.sharding.get_abstract_mesh()
     return None if mesh.empty else mesh

@@ -41,18 +41,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.6 exposes this as TPUCompilerParams; newer jax renamed it
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
 def _decode_kernel(
+    len_ref,      # (B,) cache_len (already includes the T new tokens), SMEM
     q_ref,        # (1, 1, TGp, D)
     k_ref,        # (1, 1, bk, D)
     v_ref,        # (1, 1, bk, D)
-    pos_ref,      # (1, bk) absolute slot positions
-    len_ref,      # (1, 1) cache_len (already includes the T new tokens)
+    pos_ref,      # (1, 1, bk) absolute slot positions
     o_ref,        # (1, 1, TGp, D)
     m_ref, l_ref, acc_ref,
     *,
@@ -62,6 +59,7 @@ def _decode_kernel(
     window: Optional[int],
     block_k: int,
 ):
+    b = pl.program_id(0)
     ik = pl.program_id(2)
     ns = pl.num_programs(2)
 
@@ -78,11 +76,11 @@ def _decode_kernel(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )  # (TGp, bk)
 
-    cache_len = len_ref[0, 0]
+    cache_len = len_ref[b]
     row = jax.lax.broadcasted_iota(jnp.int32, (TGp, block_k), 0)
     t = row // G                                        # token index (pad rows -> t >= T)
     q_pos = cache_len - T + t
-    kv_pos = pos_ref[0][None, :]                        # (1, bk)
+    kv_pos = pos_ref[0]                                 # (1, bk)
     mask = (kv_pos >= 0) & (kv_pos <= q_pos) & (row < T * G)
     if window is not None:
         mask &= kv_pos > q_pos - window
@@ -151,34 +149,40 @@ def decode_attention_pallas(
     if TGp != TG:
         qh = jnp.pad(qh, ((0, 0), (0, 0), (0, TGp - TG), (0, 0)))
 
-    clen = cache_len.astype(jnp.int32).reshape(B, 1)
+    # Mosaic blocks must tile the last two dims by (8, 128) or span them:
+    # the lengths ride in SMEM (scalar prefetch), the positions as (B, 1, S)
+    # so a (1, 1, block_k) block spans its second-minor dim
+    pos3 = kv_positions.reshape(B, 1, S + pk)
 
     kernel = functools.partial(
         _decode_kernel, T=T, G=G, scale=scale, window=window, block_k=block_k
     )
-    out = pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B, K, ns),
         in_specs=[
-            pl.BlockSpec((1, 1, TGp, D), lambda b, kh_, ik: (b, kh_, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, kh_, ik: (b, kh_, ik, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, kh_, ik: (b, kh_, ik, 0)),
-            pl.BlockSpec((1, block_k), lambda b, kh_, ik: (b, ik)),
-            pl.BlockSpec((1, 1), lambda b, kh_, ik: (b, 0)),
+            pl.BlockSpec((1, 1, TGp, D), lambda b, kh_, ik, ln: (b, kh_, 0, 0)),
+            pl.BlockSpec((1, 1, block_k, D), lambda b, kh_, ik, ln: (b, kh_, ik, 0)),
+            pl.BlockSpec((1, 1, block_k, D), lambda b, kh_, ik, ln: (b, kh_, ik, 0)),
+            pl.BlockSpec((1, 1, block_k), lambda b, kh_, ik, ln: (b, 0, ik)),
         ],
-        out_specs=pl.BlockSpec((1, 1, TGp, D), lambda b, kh_, ik: (b, kh_, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, K, TGp, D), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, TGp, D), lambda b, kh_, ik, ln: (b, kh_, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((TGp, 1), jnp.float32),
             pltpu.VMEM((TGp, 1), jnp.float32),
             pltpu.VMEM((TGp, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, K, TGp, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="decode_attention",
-    )(qh, kh, vh, kv_positions, clen)
+    )(cache_len.astype(jnp.int32), qh, kh, vh, pos3)
 
     out = out[:, :, :TG].reshape(B, K, T, G, D).transpose(0, 2, 1, 3, 4)
     return out.reshape(B, T, H, D)
@@ -186,10 +190,10 @@ def decode_attention_pallas(
 
 def _paged_decode_kernel(
     bt_ref,       # (B, P) block table, scalar-prefetched (drives the DMA plan)
+    len_ref,      # (B,) cache_len (already includes the T new tokens), SMEM
     q_ref,        # (1, 1, TGp, D)
-    len_ref,      # (1, 1) cache_len (already includes the T new tokens)
-    k_ref,        # (1, ps, 1, D) one page of one KV head
-    v_ref,        # (1, ps, 1, D)
+    k_ref,        # (1, 1, ps, D) one page of one KV head
+    v_ref,        # (1, 1, ps, D)
     o_ref,        # (1, 1, TGp, D)
     m_ref, l_ref, acc_ref,
     *,
@@ -211,12 +215,12 @@ def _paged_decode_kernel(
 
     TGp = q_ref.shape[2]
     q = q_ref[0, 0].astype(jnp.float32) * scale        # (TGp, D)
-    k = k_ref[0, :, 0].astype(jnp.float32)             # (ps, D)
+    k = k_ref[0, 0].astype(jnp.float32)                # (ps, D)
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )  # (TGp, ps)
 
-    cache_len = len_ref[0, 0]
+    cache_len = len_ref[b]
     page = bt_ref[b, ip]
     row = jax.lax.broadcasted_iota(jnp.int32, (TGp, page_size), 0)
     t = row // G                                        # token index (pad rows -> t >= T)
@@ -238,7 +242,7 @@ def _paged_decode_kernel(
     corr = jnp.exp(m_prev - m_new)
     l_ref[...] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
     m_ref[...] = m_new
-    v = v_ref[0, :, 0].astype(jnp.float32)
+    v = v_ref[0, 0].astype(jnp.float32)
     acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
@@ -256,7 +260,7 @@ def _paged_decode_kernel(
 )
 def decode_attention_paged_pallas(
     q: jax.Array,          # (B, T, H, D)
-    k_pages: jax.Array,    # (n_pages, ps, K, D) global page pool
+    k_pages: jax.Array,    # (n_pages, K, ps, D) global head-major page pool
     v_pages: jax.Array,
     cache_len: jax.Array,  # (B,) valid length INCLUDING the T new tokens
     block_tables: jax.Array,  # (B, P) page indices, -1 = unallocated
@@ -269,14 +273,16 @@ def decode_attention_paged_pallas(
 
     Same tiling as :func:`decode_attention_pallas` except the sequential
     axis walks the per-row block table: grid step ``(b, h, ip)`` streams
-    page ``block_tables[b, ip]`` of the pool.  The table is scalar-prefetched
-    (``PrefetchScalarGridSpec``) so the page index is known before the DMA
-    issues — the standard PagedAttention TPU pattern.  Unallocated entries
+    head ``h`` of page ``block_tables[b, ip]`` of the pool — one contiguous
+    ``(ps, D)`` tile, since the pool is head-major.  The table and the
+    lengths are scalar-prefetched (``PrefetchScalarGridSpec``) so the page
+    index is known before the DMA issues — the standard PagedAttention TPU
+    pattern.  Unallocated entries
     (-1) clamp to page 0 and mask to -inf, costing one redundant page fetch
     per hole rather than a branch.
     """
     B, T, H, D = q.shape
-    n_pages, ps, K, _ = k_pages.shape
+    n_pages, K, ps, _ = k_pages.shape
     P = block_tables.shape[1]
     assert H % K == 0
     G = H // K
@@ -288,27 +294,22 @@ def decode_attention_paged_pallas(
     if TGp != TG:
         qh = jnp.pad(qh, ((0, 0), (0, 0), (0, TGp - TG), (0, 0)))
 
-    clen = cache_len.astype(jnp.int32).reshape(B, 1)
-
     kernel = functools.partial(
         _paged_decode_kernel, T=T, G=G, scale=scale, window=window, page_size=ps
     )
+    page_spec = pl.BlockSpec(
+        (1, 1, ps, D),
+        lambda b, h, ip, bt, ln: (jnp.maximum(bt[b, ip], 0), h, 0, 0),
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(B, K, P),
         in_specs=[
-            pl.BlockSpec((1, 1, TGp, D), lambda b, h, ip, bt: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b, h, ip, bt: (b, 0)),
-            pl.BlockSpec(
-                (1, ps, 1, D),
-                lambda b, h, ip, bt: (jnp.maximum(bt[b, ip], 0), 0, h, 0),
-            ),
-            pl.BlockSpec(
-                (1, ps, 1, D),
-                lambda b, h, ip, bt: (jnp.maximum(bt[b, ip], 0), 0, h, 0),
-            ),
+            pl.BlockSpec((1, 1, TGp, D), lambda b, h, ip, bt, ln: (b, h, 0, 0)),
+            page_spec,
+            page_spec,
         ],
-        out_specs=pl.BlockSpec((1, 1, TGp, D), lambda b, h, ip, bt: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, TGp, D), lambda b, h, ip, bt, ln: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((TGp, 1), jnp.float32),
             pltpu.VMEM((TGp, 1), jnp.float32),
@@ -319,12 +320,13 @@ def decode_attention_paged_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, TGp, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="decode_attention_paged",
-    )(block_tables.astype(jnp.int32), qh, clen, k_pages, v_pages)
+    )(block_tables.astype(jnp.int32), cache_len.astype(jnp.int32), qh,
+      k_pages, v_pages)
 
     out = out[:, :, :TG].reshape(B, K, T, G, D).transpose(0, 2, 1, 3, 4)
     return out.reshape(B, T, H, D)

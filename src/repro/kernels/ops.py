@@ -1,13 +1,12 @@
 """Public kernel API with backend dispatch.
 
-On TPU the Pallas kernels are used; everywhere else (this CPU container, any
-GPU fallback) the chunked pure-jnp references run.  ``force_ref=True`` (or the
-``REPRO_FORCE_REF_KERNELS`` env var) pins the reference path — the dry-run
-uses it so lowering succeeds on the CPU host platform.
+On TPU the Pallas kernels are used; on every other backend the chunked
+pure-jnp references run (``interpret=True`` runs the Pallas kernels in the
+interpreter instead, which is how the CPU tests cover them).  On a TPU only
+an explicit ``force_ref=True`` argument picks the reference.
 """
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
@@ -16,8 +15,6 @@ from repro.kernels import ref
 
 
 def _use_pallas() -> bool:
-    if os.environ.get("REPRO_FORCE_REF_KERNELS"):
-        return False
     return jax.default_backend() == "tpu"
 
 

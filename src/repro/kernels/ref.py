@@ -201,7 +201,7 @@ def decode_attention(
 
 def decode_attention_paged(
     q: jax.Array,          # (B, T, H, D)
-    k_pages: jax.Array,    # (n_pages, ps, K, D) global page pool
+    k_pages: jax.Array,    # (n_pages, K, ps, D) global head-major page pool
     v_pages: jax.Array,
     cache_len: jax.Array,  # (B,) valid length INCLUDING the T new tokens
     block_tables: jax.Array,  # (B, P) page indices into the pool, -1 = unset
@@ -218,22 +218,21 @@ def decode_attention_paged(
     exactly once in the paged layout — no ring wrap), so ``kv_positions`` is
     implicit; unallocated table entries (-1) mask their whole page.
     """
-    n_pages, ps, K, D = k_pages.shape
+    n_pages, K, ps, D = k_pages.shape
     B, P = block_tables.shape
-    idx = (
-        jnp.clip(block_tables, 0, n_pages - 1)[:, :, None] * ps
-        + jnp.arange(ps)[None, None, :]
-    ).reshape(B, P * ps)
-    k = k_pages.reshape(n_pages * ps, K, D)[idx]  # (B, S, K, D)
-    v = v_pages.reshape(n_pages * ps, K, D)[idx]
+    pages = jnp.clip(block_tables, 0, n_pages - 1)
+
+    def rows(pool):  # (B, P, K, ps, D) -> (B, P*ps, K, D)
+        return pool[pages].transpose(0, 1, 3, 2, 4).reshape(B, P * ps, K, D)
+
     kv_pos = jnp.where(
         jnp.repeat(block_tables, ps, axis=1) >= 0,
         jnp.arange(P * ps, dtype=jnp.int32)[None, :],
         -1,
     )
     return decode_attention(
-        q, k, v, cache_len, kv_positions=kv_pos, window=window, scale=scale,
-        causal=causal,
+        q, rows(k_pages), rows(v_pages), cache_len, kv_positions=kv_pos,
+        window=window, scale=scale, causal=causal,
     )
 
 

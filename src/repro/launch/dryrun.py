@@ -14,6 +14,11 @@ Usage:
 cell (compile state isolation + restartability); results land in
 experiments/dryrun/<mesh>_<arch>_<shape>.json and EXPERIMENTS.md §Dry-run is
 generated from them.
+
+This is a CPU tool: it compiles for 512 virtual host devices.  One child
+process per cell is acceptable only because no child touches an
+accelerator — on a chip, one process owns the device, and a child that
+needs it fails or hangs while its parent holds it.
 """
 import argparse
 import json

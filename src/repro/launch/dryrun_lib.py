@@ -7,7 +7,6 @@ Import this ONLY from an entrypoint that has already set
 from __future__ import annotations
 
 import dataclasses
-import os
 import re
 import time
 from typing import Any, Dict
@@ -25,8 +24,9 @@ from repro.distributed.sharding import (
     unzip_params,
     use_rules,
 )
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16, make_production_mesh, mesh_chips
+from repro.launch.mesh import make_production_mesh, mesh_chips
 from repro.models import build_model
+from repro.serving.cost_model import TPU_V5E
 from repro.training.optimizer import OptConfig
 from repro.training.train_loop import make_train_step, opt_state_axes
 
@@ -175,7 +175,6 @@ def lower_cell(
     if not ok:
         return CellResult(arch, shape_name, mesh_kind, "skipped", 0.0, error=why)
 
-    os.environ["REPRO_FORCE_REF_KERNELS"] = "1"  # jnp path lowers on cpu hosts
     mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
     chips = mesh_chips(mesh)
     model = build_model(cfg)
@@ -198,7 +197,7 @@ def lower_cell(
     params_sds, params_axes = unzip_params(params_p)
     params_sh = _shardings(params_axes, params_sds, mesh, rules)
 
-    with mesh, use_rules(rules):
+    with jax.set_mesh(mesh), use_rules(rules):
         if shape.kind == "train":
             init_opt, train_step = make_train_step(model, OptConfig())
             opt_sds = jax.eval_shape(init_opt, params_sds)
@@ -268,9 +267,10 @@ def lower_cell(
 
 
 def roofline_terms(res: CellResult, chips: int) -> Dict[str, float]:
-    """Three-term roofline (seconds) from per-device dry-run stats."""
+    """Three-term roofline (seconds) from per-device dry-run stats, priced
+    on the v5e chips the production mesh is made of."""
     return {
-        "compute_s": res.flops_per_device / PEAK_FLOPS_BF16,
-        "memory_s": res.bytes_per_device / HBM_BW,
-        "collective_s": res.collectives.get("total", 0) / ICI_BW,
+        "compute_s": res.flops_per_device / TPU_V5E.peak_flops,
+        "memory_s": res.bytes_per_device / TPU_V5E.hbm_bw,
+        "collective_s": res.collectives.get("total", 0) / TPU_V5E.interconnect_bw,
     }

@@ -7,27 +7,16 @@ device initialisation.
 """
 from __future__ import annotations
 
-from typing import Optional
-
 import jax
-
-# TPU v5e hardware constants (roofline targets; see EXPERIMENTS.md §Roofline)
-PEAK_FLOPS_BF16 = 197e12      # per chip
-HBM_BW = 819e9                # bytes/s per chip
-ICI_BW = 50e9                 # bytes/s per link
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_debug_mesh(data: int = 2, model: int = 2, pod: Optional[int] = None):
-    """Small mesh for CPU tests (requires enough host devices)."""
-    if pod:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+    # Auto axes: GSPMD propagates shardings from the logical-axis
+    # constraints, as the model code expects under ``jax.set_mesh``
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def mesh_chips(mesh) -> int:

@@ -98,6 +98,9 @@ def main(argv=None) -> Dict[str, Any]:
 
     # heavy imports (jax &c) only after argument parsing
     from repro.api import ServeConfig, StreamServe
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.config:
         base = ServeConfig.from_yaml(args.config)

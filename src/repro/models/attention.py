@@ -298,31 +298,32 @@ def write_pages(
 ) -> Tuple[jax.Array, jax.Array]:
     """Scatter T new KV entries into a global page pool via block tables.
 
-    pool_k/v: (n_pages, ps, K, D); k/v_new: (B, T, K, D); block_tables:
-    (B, P) page indices (-1 = unallocated); start_pos: (B,).  Position ``p``
-    of row ``b`` lands in slot ``p % ps`` of page ``block_tables[b, p // ps]``
-    — positions are written exactly once (no ring wrap; the block table is
-    sized for the full context), so the paged decode mask can reconstruct
-    positions from page indices alone.  Writes whose page entry is missing
-    (or beyond the table) drop: inactive rows and bucket padding never touch
-    live pages.
+    pool_k/v: (n_pages, K, ps, D) — head-major, so one head of one page is a
+    contiguous (ps, D) tile for the paged decode kernel; k/v_new: (B, T, K,
+    D); block_tables: (B, P) page indices (-1 = unallocated); start_pos:
+    (B,).  Position ``p`` of row ``b`` lands in slot ``p % ps`` of page
+    ``block_tables[b, p // ps]`` — positions are written exactly once (no
+    ring wrap; the block table is sized for the full context), so the paged
+    decode mask can reconstruct positions from page indices alone.  Writes
+    whose page entry is missing (or beyond the table) drop: inactive rows and
+    bucket padding never touch live pages.
     """
-    n_pages, ps, K, D = pool_k.shape
+    n_pages, K, ps, D = pool_k.shape
     B, T = k_new.shape[:2]
     P = block_tables.shape[1]
     pos = start_pos[:, None] + jnp.arange(T)[None, :]          # (B, T)
     pidx = pos // ps
     page = jnp.take_along_axis(block_tables, jnp.clip(pidx, 0, P - 1), axis=1)
-    page = jnp.where(pidx < P, page, -1)
-    flat = jnp.where(page >= 0, page * ps + pos % ps, n_pages * ps)  # OOB drops
-    flat = flat.reshape(B * T)
-    kf = pool_k.reshape(n_pages * ps, K, D).at[flat].set(
+    page = jnp.where((pidx < P) & (page >= 0), page, n_pages)  # OOB drops
+    page = page.reshape(B * T)
+    slot = (pos % ps).reshape(B * T)
+    kf = pool_k.at[page, :, slot].set(
         k_new.reshape(B * T, K, D).astype(pool_k.dtype), mode="drop"
     )
-    vf = pool_v.reshape(n_pages * ps, K, D).at[flat].set(
+    vf = pool_v.at[page, :, slot].set(
         v_new.reshape(B * T, K, D).astype(pool_v.dtype), mode="drop"
     )
-    return kf.reshape(n_pages, ps, K, D), vf.reshape(n_pages, ps, K, D)
+    return kf, vf
 
 
 def attention_decode(
@@ -338,7 +339,7 @@ def attention_decode(
     ``cache`` = {"k", "v", "kv_pos"}; ``cache_len`` (B,) is the committed
     length BEFORE these tokens.  Query i sits at absolute position
     cache_len + i.  With ``block_tables`` the cache is instead the global
-    page pool {"k", "v"}: (n_pages, ps, K, D) — writes and attention go
+    page pool {"k", "v"}: (n_pages, K, ps, D) — writes and attention go
     through the per-row tables (paged layout; requires full attention, the
     engine gates SWA off).
     """
@@ -409,9 +410,10 @@ def init_decode_cache(cfg: ArchConfig, batch: int, max_len: int, dtype) -> dict:
 
 
 def init_page_pool(cfg: ArchConfig, n_pages: int, page_size: int, dtype) -> dict:
-    """Global paged KV pool shared by all decode slots (one per attn layer)."""
+    """Global paged KV pool shared by all decode slots (one per attn layer),
+    head-major: (n_pages, K, page_size, D)."""
     K, D = cfg.n_kv_heads, cfg.head_dim
     return {
-        "k": jnp.zeros((n_pages, page_size, K, D), dtype),
-        "v": jnp.zeros((n_pages, page_size, K, D), dtype),
+        "k": jnp.zeros((n_pages, K, page_size, D), dtype),
+        "v": jnp.zeros((n_pages, K, page_size, D), dtype),
     }

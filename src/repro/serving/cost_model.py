@@ -9,12 +9,11 @@ EXPERIMENTS.md §Roofline.
 
 Hardware profiles
 -----------------
-``TPU_V5E``  — the reproduction target (197 TFLOP/s bf16, 819 GB/s HBM,
-               ~50 GB/s/link ICI).  A "lane" is the model-parallel submesh
-               a prefill or decode worker runs on.
-``A800_40G`` — the paper's hardware, kept for fidelity checks of the
-               paper's *relative* claims (§4): 312 TFLOP/s fp16 dense,
-               1555 GB/s HBM, 400 GB/s NVLink.
+``PEAKS`` holds one row per accelerator, keyed by ``jax.Device.device_kind``
+(peak FLOP/s and HBM bandwidth per chip, with their source).  A "lane" is
+the model-parallel submesh a prefill or decode worker runs on.
+:func:`device_profile` looks up the device the program runs on; a kind that
+is not in the table raises instead of borrowing another chip's peaks.
 
 Every op cost is ``max(compute_time, memory_time) + dispatch_overhead``
 — the roofline max, not the sum, because TPU/GPU DMA overlaps compute.
@@ -22,7 +21,9 @@ Every op cost is ``max(compute_time, memory_time) + dispatch_overhead``
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Dict, Optional
+
+import jax
 
 from repro.configs.base import ArchConfig
 
@@ -37,23 +38,41 @@ class HardwareProfile:
     host_staged_bw: float      # bytes/s for the "w/o NIXL" fallback path
 
 
-TPU_V5E = HardwareProfile(
-    name="tpu-v5e",
-    peak_flops=197e12,
-    hbm_bw=819e9,
-    interconnect_bw=50e9,      # one ICI link
-    dispatch_overhead=25e-6,
-    host_staged_bw=8e9,        # PCIe-staged host bounce
-)
+# Peak rates per chip, keyed by device_kind.
+PEAKS: Dict[str, HardwareProfile] = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at
+    # 819 GB/s, 1,600 Gbit/s of ICI per chip (50 GB/s per link of four).
+    "TPU v5 lite": HardwareProfile(
+        name="tpu-v5e",
+        peak_flops=197e12,
+        hbm_bw=819e9,
+        interconnect_bw=50e9,      # one ICI link
+        dispatch_overhead=25e-6,
+        host_staged_bw=8e9,        # PCIe-staged host bounce
+    ),
+}
 
-A800_40G = HardwareProfile(
-    name="a800-40g",
-    peak_flops=312e12,
-    hbm_bw=1555e9,
-    interconnect_bw=400e9,     # NVLink
-    dispatch_overhead=40e-6,
-    host_staged_bw=12e9,
-)
+TPU_V5E = PEAKS["TPU v5 lite"]
+
+
+def device_profile(device: Optional[jax.Device] = None) -> HardwareProfile:
+    """Peak rates of ``device`` (default: the first JAX device).
+
+    The CPU backend has no row of its own: it prices with the v5e row, an
+    explicit stand-in that keeps the engine's tick pricing in CPU tests
+    equal to the target chip's.  Any other device kind missing from
+    :data:`PEAKS` raises.
+    """
+    device = device or jax.devices()[0]
+    if device.platform == "cpu":
+        return TPU_V5E
+    try:
+        return PEAKS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak rates for device kind {device.device_kind!r}; add its "
+            f"row, with a source, to repro.serving.cost_model.PEAKS"
+        ) from None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -227,10 +246,9 @@ class PrefillDelayEstimator:
     TTFT-slack term and the EDF admission guard need to see.
     """
 
-    def __init__(self, cfg: ArchConfig, hw: HardwareProfile = TPU_V5E,
-                 max_batch: int = 8, mean_context: int = 256,
-                 prefill_chunk: Optional[int] = None):
-        self.cost = CostModel(cfg, hw=hw)
+    def __init__(self, cfg: ArchConfig, max_batch: int = 8,
+                 mean_context: int = 256, prefill_chunk: Optional[int] = None):
+        self.cost = CostModel(cfg, hw=device_profile())
         self.tick_s = self.cost.decode_step_time(max_batch, max(mean_context, 1))
         self.prefill_chunk = prefill_chunk
 

@@ -301,6 +301,25 @@ def test_estimator_chunk_pricing(tiny_model):
     assert cm.chunked_prefill_time(0, 128) == cm.hw.dispatch_overhead
 
 
+def test_device_profile_lookup():
+    """Peaks come from the device the program runs on: a listed kind
+    resolves to its row, the CPU backend to the documented v5e stand-in, and
+    an unlisted accelerator raises instead of borrowing another chip's
+    peaks."""
+    import types
+
+    import jax
+
+    from repro.serving.cost_model import PEAKS, TPU_V5E, device_profile
+
+    v5e = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    assert device_profile(v5e) is PEAKS["TPU v5 lite"]
+    assert TPU_V5E.peak_flops == 197e12 and TPU_V5E.hbm_bw == 819e9
+    assert device_profile(jax.devices("cpu")[0]) is TPU_V5E
+    with pytest.raises(ValueError, match="no peak rates"):
+        device_profile(types.SimpleNamespace(platform="gpu", device_kind="H100"))
+
+
 def test_serveconfig_chunk_knobs_round_trip():
     from repro.api import ServeConfig
 

@@ -11,24 +11,17 @@ import subprocess
 import sys
 import textwrap
 
-import jax
 import pytest
-
-if not hasattr(jax, "shard_map"):
-    pytest.skip(
-        "distributed paths target the jax.shard_map / jax.set_mesh API "
-        "(jax >= 0.6); this environment has an older jax",
-        allow_module_level=True,
-    )
 
 SCRIPT = textwrap.dedent(
     """
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, jax.numpy as jnp, numpy as np, json
-    from jax.sharding import NamedSharding, PartitionSpec as PS
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as PS
 
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    AUTO = (AxisType.Auto, AxisType.Auto)
+    mesh = jax.make_mesh((2, 2), ("data", "model"), axis_types=AUTO)
     out = {}
 
     # ---------------- MoE: shard_map vs global dispatch ----------------
@@ -44,7 +37,7 @@ SCRIPT = textwrap.dedent(
 
     ref, _ = M.apply_moe_global(p, cfg, x, capacity_factor=8.0)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         xs = jax.device_put(x, NamedSharding(mesh, PS("data", None, None)))
         ps = jax.tree.map(
             lambda w: jax.device_put(w, NamedSharding(mesh, PS("model", None, None)))
@@ -65,8 +58,8 @@ SCRIPT = textwrap.dedent(
     p2 = jax.tree.map(lambda q: q.value if hasattr(q, "value") else q, p2,
                       is_leaf=lambda x: hasattr(x, "value"))
     ref2, _ = M.apply_moe_global(p2, cfg2, x, capacity_factor=8.0)
-    mesh2 = jax.make_mesh((1, 4), ("data", "model"))
-    with mesh2:
+    mesh2 = jax.make_mesh((1, 4), ("data", "model"), axis_types=AUTO)
+    with jax.set_mesh(mesh2):
         xs2 = jax.device_put(x, NamedSharding(mesh2, PS("data", None, None)))
         ps2 = jax.tree.map(
             lambda w: jax.device_put(w, NamedSharding(mesh2, PS())), p2
@@ -99,7 +92,7 @@ SCRIPT = textwrap.dedent(
     ck2, cv2, cp2 = A.write_cache(ck, cv, cp, kn, vn, clen)
     want = R.decode_attention(q, ck2, cv2, clen + T, kv_positions=cp2)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         qd = jax.device_put(q, NamedSharding(mesh, PS("data", None, None, None)))
         cached = {
             "k": jax.device_put(ck, NamedSharding(mesh, PS("data", "model", None, None))),
@@ -138,9 +131,8 @@ SCRIPT = textwrap.dedent(
 
 @pytest.fixture(scope="module")
 def results():
-    env = dict(os.environ, PYTHONPATH="src")
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
-    env.pop("JAX_PLATFORMS", None)
     proc = subprocess.run(
         [sys.executable, "-c", SCRIPT], capture_output=True, text=True,
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))), env=env,

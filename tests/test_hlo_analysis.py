@@ -6,7 +6,6 @@ whose true costs are computable by hand.
 """
 import jax
 import jax.numpy as jnp
-import pytest
 
 from repro.launch.hlo_analysis import analyze
 
@@ -61,10 +60,6 @@ def test_bytes_order_of_magnitude():
     assert 0.5 * want <= c.bytes <= 4 * want, (c.bytes, want)
 
 
-@pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="subprocess script targets the jax.shard_map API (jax >= 0.6)",
-)
 def test_collective_detection():
     """psum under shard_map shows up as all-reduce bytes."""
     import subprocess, sys, textwrap, os, json
@@ -81,9 +76,8 @@ def test_collective_detection():
         c = analyze(hlo)
         print(json.dumps({"ar": c.collectives.get("all-reduce", 0)}))
     """)
-    env = dict(os.environ, PYTHONPATH="src")
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
-    env.pop("JAX_PLATFORMS", None)
     proc = subprocess.run([sys.executable, "-c", script], capture_output=True,
                           text=True, timeout=240,
                           cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),

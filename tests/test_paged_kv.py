@@ -34,15 +34,16 @@ def _paged_case(B=2, T=3, P=4, ps=16, K=2, D=32, n_pages=32):
     # scatter each row's pages to distinct shuffled pool slots; leave a
     # ragged tail of the table unallocated (-1) past the valid length
     perm = RNG.permutation(n_pages)[: B * P].reshape(B, P)
-    k_pool = jnp.asarray(RNG.normal(size=(n_pages, ps, K, D)), jnp.float32)
-    v_pool = jnp.asarray(RNG.normal(size=(n_pages, ps, K, D)), jnp.float32)
+    k_pool = jnp.asarray(RNG.normal(size=(n_pages, K, ps, D)), jnp.float32)
+    v_pool = jnp.asarray(RNG.normal(size=(n_pages, K, ps, D)), jnp.float32)
     bt = np.full((B, P), -1, np.int32)
     for b in range(B):
         pages_live = -(-int(cache_len[b]) // ps)
         for i in range(pages_live):
             bt[b, i] = perm[b, i]
-            k_pool = k_pool.at[perm[b, i]].set(k[b, i * ps : (i + 1) * ps])
-            v_pool = v_pool.at[perm[b, i]].set(v[b, i * ps : (i + 1) * ps])
+            # the pool is head-major: (K, ps, D) per page
+            k_pool = k_pool.at[perm[b, i]].set(k[b, i * ps : (i + 1) * ps].swapaxes(0, 1))
+            v_pool = v_pool.at[perm[b, i]].set(v[b, i * ps : (i + 1) * ps].swapaxes(0, 1))
     return q, k, v, k_pool, v_pool, cache_len, jnp.asarray(bt)
 
 
