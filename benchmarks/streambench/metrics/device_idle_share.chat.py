@@ -1,0 +1,7 @@
+"""Share of the traced window in which no operation ran on the device, in %:
+1 - (union of device op intervals) / window."""
+
+
+def read(ctx):
+    w = ctx.trace.window_s
+    return None if w <= 0 else 100.0 * (1.0 - ctx.trace.busy_s / w)
