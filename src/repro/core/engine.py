@@ -36,11 +36,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+from time import perf_counter
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.api.registry import (
     register_draft,
@@ -59,6 +61,7 @@ from repro.core.specustream import (
 )
 from repro.models import build_model
 from repro.models.attention import SPEC_MARGIN, cache_capacity
+from repro.obs.counters import WorkCounters
 from repro.obs.spans import request_phases
 from repro.obs.trace import (
     EV_ADMIT,
@@ -76,6 +79,14 @@ from repro.obs.trace import (
     EV_PREFILL_START,
     EV_VERIFY,
     EV_WORKER_FAIL,
+    SPAN_ADMIT,
+    SPAN_DISPATCH,
+    SPAN_DRAFT,
+    SPAN_EMIT,
+    SPAN_PUBLISH,
+    SPAN_SPEC,
+    SPAN_STEP,
+    SPAN_SYNC,
     NullRecorder,
     make_recorder,
 )
@@ -512,6 +523,7 @@ class StreamPair:
         self.acceptance = 0.7  # optimistic prior
         self.key = jax.random.PRNGKey(worker_id)
         self.healthy = True
+        self.counters = WorkCounters()
 
     # --------------------------------------------------------------- helpers
     def free_slots(self) -> List[int]:
@@ -634,53 +646,80 @@ class StreamPair:
             return self._admit_paged(reqs, now)
         slots = self.free_slots()[: len(reqs)]
         assert len(slots) == len(reqs), "admit() requires a free slot per request"
-        tr = self.trace
+        with TraceAnnotation(SPAN_DISPATCH):
+            self._start_prefill(reqs, now)
+            if self._bucketed:
+                S = self._bucket(max(len(r.prompt) for r in reqs), self._len_buckets)
+                Bb = self._bucket(len(reqs), self._admit_buckets)
+                tokens = np.zeros((Bb, S), np.int32)
+                lengths = np.ones((Bb,), np.int32)  # pad rows: 1 garbage token
+                for i, req in enumerate(reqs):
+                    tokens[i, : len(req.prompt)] = req.prompt
+                    lengths[i] = len(req.prompt)
+                batch = {"tokens": jnp.asarray(tokens), "lengths": jnp.asarray(lengths)}
+            else:
+                Bb, S = 1, len(reqs[0].prompt)  # legacy path: exact shapes, one per call
+                batch = {"tokens": jnp.asarray(list(reqs[0].prompt), jnp.int32)[None, :]}
+            self._count_prefill(sum(len(r.prompt) for r in reqs), Bb * S)
+            slot_ids = np.full((Bb,), self.econf.max_batch, np.int32)  # OOB = dropped
+            slot_ids[: len(reqs)] = slots
+            slots_dev = jnp.asarray(slot_ids)
+            last_logits, small_cache = self.lane.prefill(batch)
+            # --- KV transfer (NIXL analogue): insert into the decode lane ----
+            for req in reqs:
+                req.state = RequestState.TRANSFERRING
+            self.lane.insert_rows(slots_dev, small_cache)
+            self.draft.on_admit(self, batch, slots_dev)
+            self.key, sk = jax.random.split(self.key)
+            first = sample(sk, last_logits, self.econf.temperature).astype(jnp.int32)
+            self.pending = self.pending.at[slots_dev].set(first, mode="drop")
+        with TraceAnnotation(SPAN_SYNC):
+            first_h = np.asarray(jax.device_get(first))  # the ONE admit round-trip
+        self._start_decoding(reqs, slots, first_h, now, fused=len(reqs))
+
+    def _start_prefill(self, reqs: List[Request], now: float) -> None:
+        """Requests enter prefill: state, tick and wall stamps, trace events."""
+        w_start = perf_counter()
         for req in reqs:
             req.state = RequestState.PREFILLING
             req.t_prefill_start = now
-            if tr.enabled:
-                tr.emit(now, self.worker_id, EV_PREFILL_START, req.request_id,
-                        (req.prompt_len, req.cache_hit_tokens))
-        if self._bucketed:
-            S = self._bucket(max(len(r.prompt) for r in reqs), self._len_buckets)
-            Bb = self._bucket(len(reqs), self._admit_buckets)
-            tokens = np.zeros((Bb, S), np.int32)
-            lengths = np.ones((Bb,), np.int32)  # pad rows: 1 garbage token
+            req.w_prefill_start = w_start
+            if self.trace.enabled:
+                self.trace.emit(now, self.worker_id, EV_PREFILL_START,
+                                req.request_id,
+                                (req.prompt_len, req.cache_hit_tokens))
+
+    def _count_prefill(self, live: int, computed: int) -> None:
+        """One prefill program fed ``live`` prompt tokens and computed
+        ``computed`` positions (its bucket's rows x length)."""
+        c = self.counters
+        c.prefill_calls += 1
+        c.prefill_live_tokens += live
+        c.prefill_slot_tokens += computed
+
+    def _start_decoding(self, reqs: List[Request], slots: List[int],
+                        first_h: np.ndarray, now: float, fused: int) -> None:
+        """Host bookkeeping once admitted requests' first tokens are on the
+        host: each request takes its decode slot and its first token."""
+        w_first = perf_counter()
+        tr = self.trace
+        with TraceAnnotation(SPAN_EMIT):
             for i, req in enumerate(reqs):
-                tokens[i, : len(req.prompt)] = req.prompt
-                lengths[i] = len(req.prompt)
-            batch = {"tokens": jnp.asarray(tokens), "lengths": jnp.asarray(lengths)}
-        else:
-            Bb = 1  # legacy path: exact shapes, one admission per call
-            batch = {"tokens": jnp.asarray(list(reqs[0].prompt), jnp.int32)[None, :]}
-        slot_ids = np.full((Bb,), self.econf.max_batch, np.int32)  # OOB = dropped
-        slot_ids[: len(reqs)] = slots
-        slots_dev = jnp.asarray(slot_ids)
-        last_logits, small_cache = self.lane.prefill(batch)
-        # --- KV transfer (NIXL analogue): insert into the decode lane --------
-        for req in reqs:
-            req.state = RequestState.TRANSFERRING
-        self.lane.insert_rows(slots_dev, small_cache)
-        self.draft.on_admit(self, batch, slots_dev)
-        self.key, sk = jax.random.split(self.key)
-        first = sample(sk, last_logits, self.econf.temperature).astype(jnp.int32)
-        self.pending = self.pending.at[slots_dev].set(first, mode="drop")
-        first_h = np.asarray(jax.device_get(first))  # the ONE admit round-trip
-        for i, req in enumerate(reqs):
-            tok = int(first_h[i])
-            req.state = RequestState.DECODING
-            req.t_prefill_end = now
-            req.t_first_token = now
-            req.output_tokens.append(tok)
-            req.token_times.append(now)
-            self.slot_req[slots[i]] = req
-            self.histories[slots[i]] = [*req.prompt, tok]
-            self._spec_reset_slot(slots[i])  # fresh request, fresh EMA
-            if tr.enabled:
-                tr.emit(now, self.worker_id, EV_PREFILL_END, req.request_id,
-                        (len(reqs),))
-                tr.emit(now, self.worker_id, EV_ADMIT, req.request_id,
-                        (slots[i],))
+                tok = int(first_h[i])
+                req.state = RequestState.DECODING
+                req.t_prefill_end = now
+                req.t_first_token = now
+                req.w_first_token = w_first
+                req.output_tokens.append(tok)
+                req.token_times.append(now)
+                self.slot_req[slots[i]] = req
+                self.histories[slots[i]] = [*req.prompt, tok]
+                self._spec_reset_slot(slots[i])  # fresh request, fresh EMA
+                if tr.enabled:
+                    tr.emit(now, self.worker_id, EV_PREFILL_END, req.request_id,
+                            (fused,))
+                    tr.emit(now, self.worker_id, EV_ADMIT, req.request_id,
+                            (slots[i],))
 
     def _admit_paged(self, reqs: List[Request], now: float) -> None:
         """Paged admission: ONE bucketed suffix-prefill straight into pages.
@@ -695,57 +734,40 @@ class StreamPair:
         """
         slots = self.free_slots()[: len(reqs)]
         assert len(slots) == len(reqs), "admit() requires a free slot per request"
-        tr = self.trace
-        for req in reqs:
-            req.state = RequestState.PREFILLING
-            req.t_prefill_start = now
-            if tr.enabled:
-                tr.emit(now, self.worker_id, EV_PREFILL_START, req.request_id,
-                        (req.prompt_len, req.cache_hit_tokens))
-        B = self.econf.max_batch
-        suffixes = [len(r.prompt) - r.cache_hit_tokens for r in reqs]
-        S = self._bucket(max(suffixes), self._len_buckets)
-        tokens = np.zeros((B, S), np.int32)
-        lens = np.zeros((B,), np.int32)
-        n_new = np.zeros((B,), np.int32)
-        for b, occupant in enumerate(self.slot_req):
-            if occupant is not None:  # idle rows hold their committed cursor
-                lens[b] = len(occupant.prompt) + len(occupant.output_tokens) - 1
-        for req, slot in zip(reqs, slots):
-            suffix = list(req.prompt[req.cache_hit_tokens:])
-            tokens[slot, : len(suffix)] = suffix
-            lens[slot] = req.cache_hit_tokens
-            n_new[slot] = len(suffix)
-            self._refresh_bt_row(slot, req.request_id)
-        for req in reqs:
-            req.state = RequestState.TRANSFERRING
-        last, self.lane.cache = _paged_admit_step(
-            self.lane.model.chunk_prefill, self.lane.params, self.lane.cache,
-            jnp.asarray(self._bt_host), jnp.asarray(tokens),
-            jnp.asarray(lens), jnp.asarray(n_new),
-        )
-        self._bt_dirty = False  # the admit step installed the fresh tables
-        self.key, sk = jax.random.split(self.key)
-        first = sample(sk, last, self.econf.temperature).astype(jnp.int32)
-        slots_dev = jnp.asarray(np.asarray(slots, np.int32))
-        first_rows = first[slots_dev]
-        self.pending = self.pending.at[slots_dev].set(first_rows, mode="drop")
-        first_h = np.asarray(jax.device_get(first_rows))  # the ONE admit round-trip
-        for i, req in enumerate(reqs):
-            tok = int(first_h[i])
-            req.state = RequestState.DECODING
-            req.t_prefill_end = now
-            req.t_first_token = now
-            req.output_tokens.append(tok)
-            req.token_times.append(now)
-            self.slot_req[slots[i]] = req
-            self.histories[slots[i]] = [*req.prompt, tok]
-            self._spec_reset_slot(slots[i])
-            if tr.enabled:
-                tr.emit(now, self.worker_id, EV_PREFILL_END, req.request_id,
-                        (len(reqs),))
-                tr.emit(now, self.worker_id, EV_ADMIT, req.request_id,
-                        (slots[i],))
+        with TraceAnnotation(SPAN_DISPATCH):
+            self._start_prefill(reqs, now)
+            B = self.econf.max_batch
+            suffixes = [len(r.prompt) - r.cache_hit_tokens for r in reqs]
+            S = self._bucket(max(suffixes), self._len_buckets)
+            self._count_prefill(sum(suffixes), B * S)
+            tokens = np.zeros((B, S), np.int32)
+            lens = np.zeros((B,), np.int32)
+            n_new = np.zeros((B,), np.int32)
+            for b, occupant in enumerate(self.slot_req):
+                if occupant is not None:  # idle rows hold their committed cursor
+                    lens[b] = len(occupant.prompt) + len(occupant.output_tokens) - 1
+            for req, slot in zip(reqs, slots):
+                suffix = list(req.prompt[req.cache_hit_tokens:])
+                tokens[slot, : len(suffix)] = suffix
+                lens[slot] = req.cache_hit_tokens
+                n_new[slot] = len(suffix)
+                self._refresh_bt_row(slot, req.request_id)
+            for req in reqs:
+                req.state = RequestState.TRANSFERRING
+            last, self.lane.cache = _paged_admit_step(
+                self.lane.model.chunk_prefill, self.lane.params, self.lane.cache,
+                jnp.asarray(self._bt_host), jnp.asarray(tokens),
+                jnp.asarray(lens), jnp.asarray(n_new),
+            )
+            self._bt_dirty = False  # the admit step installed the fresh tables
+            self.key, sk = jax.random.split(self.key)
+            first = sample(sk, last, self.econf.temperature).astype(jnp.int32)
+            slots_dev = jnp.asarray(np.asarray(slots, np.int32))
+            first_rows = first[slots_dev]
+            self.pending = self.pending.at[slots_dev].set(first_rows, mode="drop")
+        with TraceAnnotation(SPAN_SYNC):
+            first_h = np.asarray(jax.device_get(first_rows))  # the ONE admit round-trip
+        self._start_decoding(reqs, slots, first_h, now, fused=len(reqs))
 
     # --------------------------------------------------------- chunked prefill
     def _chunk_pull(self, scheduler, now: float) -> None:
@@ -823,20 +845,24 @@ class StreamPair:
                         req.request_id, (cur,))
         self._chunk_last = req.request_id
         req.prefill_active_ticks += 1  # a lane turn actually granted
-        n = min(C, len(req.prompt) - cur)
-        tokens = np.zeros((R, C), np.int32)
-        tokens[row, :n] = req.prompt[cur : cur + n]
-        lens = np.zeros((R,), np.int32)
-        for r, rq in enumerate(self.chunk_rows):
-            if rq is not None:
-                lens[r] = self.chunk_cursor[rq.request_id]
-        n_new = np.zeros((R,), np.int32)
-        n_new[row] = n
-        last_logits, self.chunk_cache = _chunk_step(
-            self.lane.model.chunk_prefill, self.chunk_cache, self.lane.params,
-            jnp.asarray(tokens), jnp.asarray(lens), jnp.asarray(n_new),
-            np.int32(row), np.int32(max(n - 1, 0)),
-        )
+        with TraceAnnotation(SPAN_DISPATCH):
+            n = min(C, len(req.prompt) - cur)
+            tokens = np.zeros((R, C), np.int32)
+            tokens[row, :n] = req.prompt[cur : cur + n]
+            lens = np.zeros((R,), np.int32)
+            for r, rq in enumerate(self.chunk_rows):
+                if rq is not None:
+                    lens[r] = self.chunk_cursor[rq.request_id]
+            n_new = np.zeros((R,), np.int32)
+            n_new[row] = n
+            self._count_prefill(n, R * C)
+            if cur == 0:  # the request's first chunk: its prefill starts now
+                req.w_prefill_start = perf_counter()
+            last_logits, self.chunk_cache = _chunk_step(
+                self.lane.model.chunk_prefill, self.chunk_cache, self.lane.params,
+                jnp.asarray(tokens), jnp.asarray(lens), jnp.asarray(n_new),
+                np.int32(row), np.int32(max(n - 1, 0)),
+            )
         cur += n
         self.chunk_cursor[req.request_id] = cur
         if tr.enabled:
@@ -851,46 +877,36 @@ class StreamPair:
         sample the first token."""
         slot = self.free_slots()[0]  # guaranteed by the _chunk_pull budget
         req.state = RequestState.TRANSFERRING
-        if self._paged:
-            # the dense chunk row becomes whole pages in the global pool;
-            # pages past the prompt keep the pool-size sentinel (dropped)
-            ps = self.econf.kv_block_size
-            bids = self.kv.seqs[req.request_id].block_ids
-            n_pages = -(-len(req.prompt) // ps)
-            page_ids = np.full((self.econf.max_len // ps,),
-                               self.kv.pool.n_blocks, np.int32)
-            page_ids[:n_pages] = bids[:n_pages]
-            self.lane.cache = _tree_insert_pages(
-                self.lane.cache, self.chunk_cache["blocks"], jnp.int32(row),
-                jnp.asarray(page_ids), jnp.int32(slot),
-                jnp.int32(len(req.prompt)),
-            )
-            self._refresh_bt_row(slot, req.request_id)
-        else:
-            slot_ids = np.full((len(self.chunk_rows),), self.econf.max_batch, np.int32)
-            slot_ids[row] = slot
-            self.lane.insert_rows(jnp.asarray(slot_ids), self.chunk_cache)
-        self.key, sk = jax.random.split(self.key)
-        first = sample(sk, last_logits, self.econf.temperature).astype(jnp.int32)
-        self.pending = self.pending.at[jnp.asarray([slot])].set(first, mode="drop")
-        tok = int(np.asarray(jax.device_get(first))[0])
-        req.state = RequestState.DECODING
-        req.t_prefill_end = now
-        req.t_first_token = now
-        req.output_tokens.append(tok)
-        req.token_times.append(now)
-        self.slot_req[slot] = req
-        self.histories[slot] = [*req.prompt, tok]
-        self._spec_reset_slot(slot)
+        with TraceAnnotation(SPAN_DISPATCH):
+            if self._paged:
+                # the dense chunk row becomes whole pages in the global pool;
+                # pages past the prompt keep the pool-size sentinel (dropped)
+                ps = self.econf.kv_block_size
+                bids = self.kv.seqs[req.request_id].block_ids
+                n_pages = -(-len(req.prompt) // ps)
+                page_ids = np.full((self.econf.max_len // ps,),
+                                   self.kv.pool.n_blocks, np.int32)
+                page_ids[:n_pages] = bids[:n_pages]
+                self.lane.cache = _tree_insert_pages(
+                    self.lane.cache, self.chunk_cache["blocks"], jnp.int32(row),
+                    jnp.asarray(page_ids), jnp.int32(slot),
+                    jnp.int32(len(req.prompt)),
+                )
+                self._refresh_bt_row(slot, req.request_id)
+            else:
+                slot_ids = np.full((len(self.chunk_rows),), self.econf.max_batch, np.int32)
+                slot_ids[row] = slot
+                self.lane.insert_rows(jnp.asarray(slot_ids), self.chunk_cache)
+            self.key, sk = jax.random.split(self.key)
+            first = sample(sk, last_logits, self.econf.temperature).astype(jnp.int32)
+            self.pending = self.pending.at[jnp.asarray([slot])].set(first, mode="drop")
+        with TraceAnnotation(SPAN_SYNC):
+            first_h = np.asarray(jax.device_get(first))
         self.chunk_rows[row] = None
         del self.chunk_cursor[req.request_id]
         if self._chunk_last == req.request_id:
             self._chunk_last = None
-        if self.trace.enabled:
-            self.trace.emit(now, self.worker_id, EV_PREFILL_END,
-                            req.request_id, (1,))
-            self.trace.emit(now, self.worker_id, EV_ADMIT, req.request_id,
-                            (slot,))
+        self._start_decoding([req], [slot], first_h, now, fused=1)
 
     def chunk_release(self, row: int) -> Request:
         """Evict a chunk row without completing it (cancel / worker failure).
@@ -905,20 +921,16 @@ class StreamPair:
         return req
 
     # ----------------------------------------------------------------- decode
-    def decode_iteration(self, now: float) -> int:
-        """One continuous-batching decode step (speculative when enabled).
-        Returns number of tokens emitted across the batch."""
-        active = self.active_slots()
-        if not active:
-            return 0
-        if self._paged:
-            self._sync_bt()  # page-table edits land before any device step
+    def _row_depths(self, active: List[int]) -> Tuple[np.ndarray, bool]:
+        """Speculation depth of each row (B,), 0 on empty slots, clamped to
+        what the draft, the verify buckets and the page margin allow; and
+        whether the rows chose their depths independently."""
         B = self.econf.max_batch
+        vb = self.econf.verify_buckets
         throughput = self.monitor.workers[self.worker_id].recent_throughput
         decision: SpecDecision = self.spec.adapt(
             self.acceptance, self.load, throughput,
         )
-        vb = self.econf.verify_buckets
         # per-row depths need both the knob and a shared verify bucket set
         # (the bucket >= max row depth is what keeps traced shapes fixed)
         per_row = (
@@ -939,95 +951,125 @@ class StreamPair:
             # extend a block table — depth past the page margin would drop
             # accepted KV on the floor
             rows = np.minimum(rows, self._kv_margin - 1)
-        k = int(rows.max())
+        return rows, per_row
+
+    def decode_iteration(self, now: float) -> int:
+        """One continuous-batching decode step (speculative when enabled).
+        Returns number of tokens emitted across the batch."""
+        active = self.active_slots()
+        if not active:
+            return 0
+        B = self.econf.max_batch
+        vb = self.econf.verify_buckets
+        with TraceAnnotation(SPAN_SPEC):
+            rows, per_row = self._row_depths(active)
+            k = int(rows.max())
+            if k:
+                for s in active:
+                    self.slot_req[s].spec_depths.append(int(rows[s]))
         active_mask = np.zeros((B,), bool)
         active_mask[active] = True
-        active_dev = jnp.asarray(active_mask)
 
         if k == 0:  # plain autoregressive step
-            logits = self.lane.decode(self.pending[:, None])
-            self.lane.commit(1, jnp.zeros((B,), jnp.int32))
-            self.key, sk = jax.random.split(self.key)
-            nxt = sample(sk, logits[:, 0], self.econf.temperature).astype(jnp.int32)
-            self.pending = jnp.where(active_dev, nxt, self.pending)
-            nxt_h = np.asarray(jax.device_get(nxt))  # the ONE decode round-trip
-            emitted = 0
-            for s in active:
-                emitted += self._emit(s, [int(nxt_h[s])], now)
-            if self.trace.enabled:
-                self.trace.emit(
-                    now, self.worker_id, EV_DECODE_STEP, None,
-                    (len(active), 0, 0, emitted, round(self.acceptance, 6),
-                     (), ()),
-                )
+            with TraceAnnotation(SPAN_DISPATCH):
+                if self._paged:
+                    self._sync_bt()  # page-table edits land before any device step
+                active_dev = jnp.asarray(active_mask)
+                logits = self.lane.decode(self.pending[:, None])
+                self.lane.commit(1, jnp.zeros((B,), jnp.int32))
+                self.key, sk = jax.random.split(self.key)
+                nxt = sample(sk, logits[:, 0], self.econf.temperature).astype(jnp.int32)
+                self.pending = jnp.where(active_dev, nxt, self.pending)
+            with TraceAnnotation(SPAN_SYNC):
+                nxt_h = np.asarray(jax.device_get(nxt))  # the ONE decode round-trip
+            with TraceAnnotation(SPAN_EMIT):
+                self.counters.decode_calls += 1
+                emitted = 0
+                for s in active:
+                    emitted += self._emit(s, [int(nxt_h[s])], now)
+                if self.trace.enabled:
+                    self.trace.emit(
+                        now, self.worker_id, EV_DECODE_STEP, None,
+                        (len(active), 0, 0, emitted, round(self.acceptance, 6),
+                         (), ()),
+                    )
             return emitted
 
         # ---- draft proposal (real depth k, padded to a shape bucket) --------
-        k_pad = pad_to_bucket(k, vb)
-        draft_toks, draft_q = self.draft.propose(self, k)
-        draft_toks = jnp.asarray(draft_toks, jnp.int32)
-        draft_q = jnp.asarray(draft_q, jnp.float32)
-        if k_pad > k:
-            draft_toks = jnp.pad(draft_toks, ((0, 0), (0, k_pad - k)), mode="edge")
-            draft_q = jnp.pad(draft_q, ((0, 0), (0, k_pad - k)), constant_values=1.0)
-        if per_row:
-            # heterogeneous (B,) depths: traced VALUES in the existing traced
-            # shape — verify_tokens already masks per-row
-            depth = jnp.asarray(rows, jnp.int32)
-        else:
-            depth = jnp.full((B,), k, jnp.int32) if vb else None
-        for s in active:
-            self.slot_req[s].spec_depths.append(int(rows[s]))
+        with TraceAnnotation(SPAN_DRAFT):
+            draft_toks, draft_q = self.draft.propose(self, k)
+        with TraceAnnotation(SPAN_DISPATCH):
+            if self._paged:
+                self._sync_bt()  # page-table edits land before any device step
+            active_dev = jnp.asarray(active_mask)
+            k_pad = pad_to_bucket(k, vb)
+            draft_toks = jnp.asarray(draft_toks, jnp.int32)
+            draft_q = jnp.asarray(draft_q, jnp.float32)
+            if k_pad > k:
+                draft_toks = jnp.pad(draft_toks, ((0, 0), (0, k_pad - k)), mode="edge")
+                draft_q = jnp.pad(draft_q, ((0, 0), (0, k_pad - k)), constant_values=1.0)
+            if per_row:
+                # heterogeneous (B,) depths: traced VALUES in the existing traced
+                # shape — verify_tokens already masks per-row
+                depth = jnp.asarray(rows, jnp.int32)
+            else:
+                depth = jnp.full((B,), k, jnp.int32) if vb else None
 
-        # ---- target verify step (T = k_pad+1 tokens, one traced shape/bucket)
-        verify_in = jnp.concatenate([self.pending[:, None], draft_toks], axis=1)
-        logits = self.lane.decode(verify_in)  # (B, k_pad+1, V)
-        self.key, sk = jax.random.split(self.key)
-        res = verify_tokens(
-            sk,
-            draft_toks,
-            draft_q,
-            logits,
-            active=active_dev,
-            temperature=self.econf.temperature,
-            depth=depth,
-        )
-        self.lane.commit(k_pad + 1, res.accept_idx)
-        self.draft.on_commit(self, res.accept_idx, k)
-        self.pending = jnp.where(active_dev, res.next_token.astype(jnp.int32), self.pending)
-        # the ONE decode round-trip: everything host bookkeeping needs at once
-        n_acc, nxt, draft_np = map(
-            np.asarray, jax.device_get((res.n_accepted, res.next_token, draft_toks))
-        )
-        if per_row:
-            # per-row acceptance: each slot's fraction of ITS OWN depth feeds
-            # the policy's per-slot EMA; the pair-level EMA keeps the mean
-            observe = getattr(self.spec, "observe_slot", None)
-            fracs = []
-            for s in active:
-                d_s = int(rows[s])
-                frac = float(n_acc[s]) / max(d_s, 1)
-                fracs.append(frac)
-                if observe is not None and d_s > 0:
-                    observe(s, frac)
-            accepted_frac = sum(fracs) / len(fracs)
-        else:
-            accepted_frac = float(n_acc[active].mean()) / max(k, 1)
-        self.acceptance = 0.8 * self.acceptance + 0.2 * accepted_frac
-
-        if self.trace.enabled:
-            self.trace.emit(now, self.worker_id, EV_VERIFY, None, (k, k_pad))
-        emitted = 0
-        for s in active:
-            toks = [*(int(t) for t in draft_np[s, : int(n_acc[s])]), int(nxt[s])]
-            emitted += self._emit(s, toks, now)
-        if self.trace.enabled:
-            self.trace.emit(
-                now, self.worker_id, EV_DECODE_STEP, None,
-                (len(active), k, k_pad, emitted, round(self.acceptance, 6),
-                 tuple(int(rows[s]) for s in active),
-                 tuple(int(n_acc[s]) for s in active)),
+            # ---- target verify step (T = k_pad+1 tokens, one traced shape/bucket)
+            verify_in = jnp.concatenate([self.pending[:, None], draft_toks], axis=1)
+            logits = self.lane.decode(verify_in)  # (B, k_pad+1, V)
+            self.key, sk = jax.random.split(self.key)
+            res = verify_tokens(
+                sk,
+                draft_toks,
+                draft_q,
+                logits,
+                active=active_dev,
+                temperature=self.econf.temperature,
+                depth=depth,
             )
+            self.lane.commit(k_pad + 1, res.accept_idx)
+            self.draft.on_commit(self, res.accept_idx, k)
+            self.pending = jnp.where(active_dev, res.next_token.astype(jnp.int32), self.pending)
+        with TraceAnnotation(SPAN_SYNC):
+            # the ONE decode round-trip: everything host bookkeeping needs at once
+            n_acc, nxt, draft_np = map(
+                np.asarray, jax.device_get((res.n_accepted, res.next_token, draft_toks))
+            )
+        with TraceAnnotation(SPAN_EMIT):
+            if per_row:
+                # per-row acceptance: each slot's fraction of ITS OWN depth feeds
+                # the policy's per-slot EMA; the pair-level EMA keeps the mean
+                observe = getattr(self.spec, "observe_slot", None)
+                fracs = []
+                for s in active:
+                    d_s = int(rows[s])
+                    frac = float(n_acc[s]) / max(d_s, 1)
+                    fracs.append(frac)
+                    if observe is not None and d_s > 0:
+                        observe(s, frac)
+                accepted_frac = sum(fracs) / len(fracs)
+            else:
+                accepted_frac = float(n_acc[active].mean()) / max(k, 1)
+            self.acceptance = 0.8 * self.acceptance + 0.2 * accepted_frac
+            c = self.counters
+            c.verify_calls += 1
+            c.spec_proposed += int(rows[active].sum())
+            c.spec_accepted += int(n_acc[active].sum())
+
+            if self.trace.enabled:
+                self.trace.emit(now, self.worker_id, EV_VERIFY, None, (k, k_pad))
+            emitted = 0
+            for s in active:
+                toks = [*(int(t) for t in draft_np[s, : int(n_acc[s])]), int(nxt[s])]
+                emitted += self._emit(s, toks, now)
+            if self.trace.enabled:
+                self.trace.emit(
+                    now, self.worker_id, EV_DECODE_STEP, None,
+                    (len(active), k, k_pad, emitted, round(self.acceptance, 6),
+                     tuple(int(rows[s]) for s in active),
+                     tuple(int(n_acc[s]) for s in active)),
+                )
         return emitted
 
     def _emit(self, slot: int, tokens: List[int], now: float) -> int:
@@ -1526,48 +1568,65 @@ class PipeServeEngine:
             raise
 
     def _step(self) -> int:
-        self._now += 1.0  # logical time; real wall time is irrelevant on CPU
-        emitted = 0
-        for pair in self.pairs:
-            if not pair.healthy:
-                continue
-            wid = pair.worker_id
+        with TraceAnnotation(SPAN_STEP):
+            # logical time: every decision reads ticks; the Request.w_* wall
+            # stamps are observation only
+            self._now += 1.0
+            return sum(self._step_pair(pair) for pair in self.pairs if pair.healthy)
+
+    def _step_pair(self, pair: StreamPair) -> int:
+        """One pair's share of a tick: admission, one decode iteration, and
+        the metrics it publishes.  Returns the tokens it emitted."""
+        wid = pair.worker_id
+        pair.counters.steps += 1
+        with TraceAnnotation(SPAN_ADMIT):
             if pair._chunk is not None:
                 # chunked prefill: one fixed-size chunk per tick, preemptible
                 # at the chunk boundary (EDF over in-progress rows + queue)
                 pair.chunk_tick(self.scheduler, self._now)
             else:
-                # stall-free admission: fill free slots from the queue, fusing
-                # up to admit_cap() reserved requests into one bucketed
-                # prefill call
-                while True:
-                    free = pair.free_slots()
-                    cap = min(len(free), pair.admit_cap())
-                    batch: List[Request] = []
-                    blocked = False
-                    while len(batch) < cap:
-                        req = self.scheduler.next_for_prefill(wid, self._now)
-                        if req is None:
-                            break
-                        if not pair.prompt_fits(req):
-                            self.scheduler.fail_request(
-                                req, self._now, "exceeds_max_context"
-                            )
-                            continue
-                        if not pair.reserve_kv(req, self._now):
-                            self.scheduler.prefill_queues[wid].appendleft(req)
-                            blocked = True
-                            break
-                        batch.append(req)
-                    if batch:
-                        pair.admit(batch, self._now)
-                    if blocked or not batch:
-                        break
-            n = pair.decode_iteration(self._now)
-            emitted += n
+                self._admit_queued(pair)
+        n = pair.decode_iteration(self._now)
+        with TraceAnnotation(SPAN_PUBLISH):
             self.monitor.record_tokens(wid, n, self._now)
             pair.publish_metrics(self.scheduler.queue_depth(wid), self._now)
-        return emitted
+        return n
+
+    def _admit_queued(self, pair: StreamPair) -> None:
+        """Stall-free admission: fill free slots from the queue, fusing up to
+        ``admit_cap()`` reserved requests into one bucketed prefill call."""
+        wid = pair.worker_id
+        while True:
+            free = pair.free_slots()
+            cap = min(len(free), pair.admit_cap())
+            batch: List[Request] = []
+            blocked = False
+            while len(batch) < cap:
+                req = self.scheduler.next_for_prefill(wid, self._now)
+                if req is None:
+                    break
+                if not pair.prompt_fits(req):
+                    self.scheduler.fail_request(
+                        req, self._now, "exceeds_max_context"
+                    )
+                    continue
+                if not pair.reserve_kv(req, self._now):
+                    self.scheduler.prefill_queues[wid].appendleft(req)
+                    blocked = True
+                    break
+                batch.append(req)
+            if batch:
+                pair.admit(batch, self._now)
+            if blocked or not batch:
+                break
+
+    def counters(self) -> Dict[str, Any]:
+        """Cumulative work counters (``repro.obs.counters``) summed over the
+        pairs, with each pair's own under ``"pairs"`` (in worker order)."""
+        per = [pair.counters.as_dict() for pair in self.pairs]
+        total: Dict[str, Any] = {k: sum(c[k] for c in per) for k in per[0]}
+        total["pairs"] = per
+        return total
 
     # ------------------------------------------------------------ StreamTrace
     def _flight_dump(self, reason: str) -> Dict[str, Any]:

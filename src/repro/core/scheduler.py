@@ -25,7 +25,10 @@ from __future__ import annotations
 
 import math
 from collections import deque
+from time import perf_counter
 from typing import Callable, Deque, Dict, List, Optional, Protocol, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from repro.core.flowguard import FlowGuard
 from repro.core.metrics import PerformanceMonitor, RequestRecord
@@ -38,6 +41,7 @@ from repro.obs.trace import (
     EV_ROUTE,
     EV_SHED,
     EV_SUBMIT,
+    SPAN_SUBMIT,
     NullRecorder,
 )
 from repro.serving.request import Request, RequestState
@@ -139,7 +143,14 @@ class StreamScheduler:
         return delay
 
     def submit(self, req: Request, now: float) -> int:
+        with TraceAnnotation(SPAN_SUBMIT):
+            return self._submit(req, now)
+
+    def _submit(self, req: Request, now: float) -> int:
         tr = self.trace
+        # first submission only: a resubmitted request keeps its stamp
+        if req.w_submit is None:
+            req.w_submit = perf_counter()
         if tr.enabled:
             tr.emit(now, -1, EV_SUBMIT, req.request_id,
                     (req.prompt_len, req.slo_ttft, req.slo_tpot))

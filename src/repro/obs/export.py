@@ -18,6 +18,7 @@ from __future__ import annotations
 import json
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+from repro.obs.counters import COUNTER_HELP
 from repro.obs.trace import (
     EV_ADMIT,
     EV_COUNTERS,
@@ -260,6 +261,9 @@ def engine_registry(engine) -> PromRegistry:
         reg.counter("streamserve_kv_lazy_evictions_total",
                     "Cached freed prefixes recycled off the FIFO free list",
                     pair.kv.pool.lazy_evictions, labels=w)
+        for name, help_ in COUNTER_HELP.items():
+            reg.counter(f"streamserve_{name}_total", help_,
+                        getattr(pair.counters, name), labels=w)
         snap = getattr(pair.spec, "snapshot", None)
         if snap is not None:
             reg.gauge("streamserve_spec_depth", "Last adaptive depth decision",

@@ -18,9 +18,7 @@ wall time.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Tuple
-
-from repro.obs.trace import EV_COUNTERS, EV_DECODE_STEP
+from typing import Optional, Tuple
 
 
 def compute_phases(
@@ -87,41 +85,3 @@ def request_phases(req) -> Tuple[float, float, float, float]:
         req.t_end,
         getattr(req, "prefill_active_ticks", 0),
     )
-
-
-def worker_timelines(events: List[Tuple]) -> Dict[int, Dict[str, float]]:
-    """Per-worker utilization summary from a trace event stream.
-
-    Occupancy is read from ``decode_step`` events (slots busy / steps);
-    queue depth from ``counters`` events.  Returns one dict per worker:
-    ``{steps, busy_steps, mean_occupancy, tokens_emitted, mean_queue_depth,
-    first_tick, last_tick}``.
-    """
-    out: Dict[int, Dict[str, float]] = {}
-    occ: Dict[int, List[int]] = {}
-    qd: Dict[int, List[float]] = {}
-    for _seq, tick, worker, etype, _rid, payload in events:
-        if worker < 0:
-            continue
-        w = out.setdefault(worker, {
-            "steps": 0, "busy_steps": 0, "tokens_emitted": 0,
-            "first_tick": tick, "last_tick": tick,
-        })
-        w["first_tick"] = min(w["first_tick"], tick)
-        w["last_tick"] = max(w["last_tick"], tick)
-        if etype == EV_DECODE_STEP:
-            occupancy, _k, _k_pad, emitted = payload[0], payload[1], payload[2], payload[3]
-            w["steps"] += 1
-            w["busy_steps"] += 1 if occupancy > 0 else 0
-            w["tokens_emitted"] += emitted
-            occ.setdefault(worker, []).append(occupancy)
-        elif etype == EV_COUNTERS:
-            qd.setdefault(worker, []).append(payload[0])
-    for worker, w in out.items():
-        rows = occ.get(worker, [])
-        w["mean_occupancy"] = round(sum(rows) / len(rows), 3) if rows else 0.0
-        depths = qd.get(worker, [])
-        w["mean_queue_depth"] = (
-            round(sum(depths) / len(depths), 3) if depths else 0.0
-        )
-    return out

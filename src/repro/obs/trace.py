@@ -92,6 +92,27 @@ SCHEMA_VERSION = "streamtrace/v1"
 # terminal event codes — traceview and the span assembler key off these
 TERMINAL_EVENTS = (EV_FINISH, EV_CANCEL, EV_FAIL)
 
+# ---------------------------------------------------------------- host spans
+# Phase spans of the program (``jax.profiler.TraceAnnotation``): they land on
+# a profiler session's host plane, on the same clock as the device ops, so a
+# trace shows what the host did while the chip sat idle.  Always on: with no
+# profiler session a span costs about a microsecond.  No span is per slot or
+# per token; every span but ``ss.submit`` lies inside an ``ss.step``.
+SPAN_SUBMIT = "ss.submit"      # StreamScheduler.submit: route + enqueue
+SPAN_STEP = "ss.step"          # one engine tick (PipeServeEngine._step)
+SPAN_ADMIT = "ss.admit"        # per pair: queue pops, KV reserve, prefill admission
+SPAN_SPEC = "ss.spec"          # speculation policy, per-row depths, depth clamps
+SPAN_DRAFT = "ss.draft"        # draft proposal (verify steps only)
+SPAN_DISPATCH = "ss.dispatch"  # building inputs and enqueueing device programs
+SPAN_SYNC = "ss.sync"          # host blocked in a device_get
+SPAN_EMIT = "ss.emit"          # per-slot token bookkeeping, finishes, frees
+SPAN_PUBLISH = "ss.publish"    # token accounting + metric publication
+
+SPAN_NAMES: Tuple[str, ...] = (
+    SPAN_SUBMIT, SPAN_STEP, SPAN_ADMIT, SPAN_SPEC, SPAN_DRAFT,
+    SPAN_DISPATCH, SPAN_SYNC, SPAN_EMIT, SPAN_PUBLISH,
+)
+
 
 class NullRecorder:
     """Zero-cost stand-in when tracing is off (the default).

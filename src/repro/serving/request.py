@@ -54,6 +54,12 @@ class Request:
     t_prefill_end: Optional[float] = None
     t_first_token: Optional[float] = None
     t_end: Optional[float] = None
+    # host wall-clock stamps (time.perf_counter seconds) beside the ticks:
+    # first submission, prefill dispatch, first token on the host.  For
+    # observation only — no routing, scheduling or depth decision reads them
+    w_submit: Optional[float] = None
+    w_prefill_start: Optional[float] = None
+    w_first_token: Optional[float] = None
     error: Optional[str] = None
     # provenance for prefix caching
     cache_hit_tokens: int = 0

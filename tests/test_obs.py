@@ -23,7 +23,7 @@ import json
 import pytest
 
 from repro.core.metrics import PerformanceMonitor, RequestRecord
-from repro.obs.spans import compute_phases, worker_timelines
+from repro.obs.spans import compute_phases
 from repro.obs.trace import (
     EV_ADMIT,
     EV_COUNTERS,
@@ -249,9 +249,6 @@ def test_chrome_trace_and_prometheus(engine_factory, trace_factory, tmp_path):
         assert f"streamserve_phase_{phase}_ticks_bucket" in txt
     # rendering is deterministic (registration order + sorted labels)
     assert txt == engine.prometheus_text()
-    tl = worker_timelines(engine.trace_events())
-    assert set(tl) == workers
-    assert all(t["steps"] > 0 and t["tokens_emitted"] > 0 for t in tl.values())
 
 
 # ------------------------------------------------------------- determinism
