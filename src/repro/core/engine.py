@@ -18,7 +18,11 @@ Hot-path shape discipline (zero steady-state retraces):
   masked inside ``verify_tokens``, so adaptive depth never changes a traced
   shape.
 * **Donated device-resident state** — the batched decode cache is donated
-  through decode/commit/insert (in-place KV update, no per-step copy);
+  through decode/commit/insert and aliased input to output.  Decode writes
+  each layer's new KV rows into the stacked cache in place (the KV rides in
+  the model's layer-scan carry: no whole-cache copy, no per-layer
+  write-back); what is still copied per step is each layer's slice that the
+  decode kernel reads (transposed head-major for the dense kernel).
   ``pending`` next-tokens live on device; ``admit`` and ``decode_iteration``
   each perform a single bulk ``jax.device_get`` for host bookkeeping.
   Donation invariant: callers must rebind ``lane.cache`` and never hold a
@@ -162,6 +166,21 @@ def _lane_commit(commit_cache, cache, n_new, accept_idx):
     return commit_cache(cache, old_len, accept_idx)
 
 
+@functools.partial(jax.jit, static_argnums=(0, 1), donate_argnums=(2,),
+                   keep_unused=True)
+def _lane_reset(init_cache, sizes, cache):
+    """An empty lane cache written into the donated buffers of ``cache``.
+
+    A fresh allocation would sit beside the old cache, whose buffers stay
+    alive until the steps queued on them finish: at warm-up's end that put
+    a second lane cache on the device and set the process's peak HBM.
+    ``keep_unused``: the old cache is read by nothing, and a pruned argument
+    donates nothing.
+    """
+    del cache
+    return init_cache(*sizes)
+
+
 @functools.partial(jax.jit, static_argnums=(0, 2))
 def _lane_prefill(prefill, params, max_len, batch):
     """Bucketed one-shot prefill (static model closure + max_len)."""
@@ -263,9 +282,12 @@ class ModelLane:
     """A model + per-slot batched decode cache + jitted step helpers.
 
     The cache is donated through every jitted step: ``decode``/``commit``/
-    ``insert_rows`` consume the previous cache buffers and update them in
-    place (no full-KV copy per step).  Callers must treat ``self.cache`` as
-    the only live handle.
+    ``insert_rows`` consume the previous cache buffers and return them
+    updated, aliased.  ``decode`` scatters only the T new KV rows per layer
+    into the stacked cache (no whole-cache copy, no whole-layer write-back);
+    the attention kernel still reads a per-layer slice copied out of it.
+    ``reset_cache`` writes the empty cache into the donated old one.
+    Callers must treat ``self.cache`` as the only live handle.
     """
 
     def __init__(self, cfg: ArchConfig, params, max_batch: int, max_len: int,
@@ -280,15 +302,17 @@ class ModelLane:
         self.kv_blocks = kv_blocks
         self.kv_block_size = kv_block_size
         self.max_context = (max_context or max_len) if paged else max_len
-        self.cache = self._init_cache()
+        init, sizes = self._cache_init()
+        self.cache = init(*sizes)
 
-    def _init_cache(self):
+    def _cache_init(self):
+        """(init function, its arguments) of this lane's empty cache."""
         if self.paged:
-            return self.model.init_paged_cache(
+            return self.model.init_paged_cache, (
                 self.max_batch, self.kv_blocks, self.kv_block_size,
                 self.max_context,
             )
-        return self.model.init_cache(self.max_batch, self.max_len)
+        return self.model.init_cache, (self.max_batch, self.max_len)
 
     def prefill(self, batch: Dict[str, Any]):
         return _lane_prefill(self.model.prefill, self.params, self.max_len, batch)
@@ -310,7 +334,7 @@ class ModelLane:
         )
 
     def reset_cache(self) -> None:
-        self.cache = self._init_cache()
+        self.cache = _lane_reset(*self._cache_init(), self.cache)
 
     @property
     def lengths(self) -> jax.Array:

@@ -9,6 +9,10 @@ empty.  Ring-buffer slots are addressed ``pos % cap``; the margin keeps
 speculative (uncommitted) writes from clobbering live window entries before a
 rollback.
 
+The model stacks each layer position's cache over the blocks of its stack
+(leading axis L); decode writes its new rows into that stack in place at the
+layer's index (``write_cache`` / ``write_pages``).
+
 Speculative rollback: rejected tokens simply leave stale slots behind; masking
 is positional (slot position <= query position), so a rewound ``cache_len``
 makes stale slots unreachable and they are overwritten on the next write.
@@ -139,24 +143,26 @@ def write_cache(
     k_new: jax.Array,
     v_new: jax.Array,
     start_pos: jax.Array,
+    layer: jax.Array,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Write T new KV entries at absolute positions start_pos + [0, T).
+    """Write layer ``layer``'s T new KV entries at absolute positions
+    start_pos + [0, T), in place in the stacked cache.
 
-    cache_k/v: (B, cap, K, D); kv_pos: (B, cap); k/v_new: (B, T, K, D);
-    start_pos: (B,).  Slots are ``position % cap`` (ring buffer).
+    cache_k/v: (L, B, cap, K, D) and kv_pos: (L, B, cap) hold every layer of
+    the stack; k/v_new: (B, T, K, D); start_pos: (B,); layer: scalar index.
+    One scatter at ``[layer, row, slot]`` per leaf, slots ``position % cap``
+    (ring buffer) — the rest of the stack is untouched.
     """
-    cap = cache_k.shape[1]
-    T = k_new.shape[1]
+    cap = cache_k.shape[2]
+    B, T = k_new.shape[:2]
     pos = start_pos[:, None] + jnp.arange(T)[None, :]  # (B, T)
     slots = (pos % cap).astype(jnp.int32)
-
-    def upd(ck, cv, cp, kn, vn, sl, ps):
-        ck = ck.at[sl].set(kn)
-        cv = cv.at[sl].set(vn)
-        cp = cp.at[sl].set(ps)
-        return ck, cv, cp
-
-    return jax.vmap(upd)(cache_k, cache_v, kv_pos, k_new, v_new, slots, pos)
+    rows = jnp.arange(B)[:, None]
+    return (
+        cache_k.at[layer, rows, slots].set(k_new),
+        cache_v.at[layer, rows, slots].set(v_new),
+        kv_pos.at[layer, rows, slots].set(pos),
+    )
 
 
 def prefill_fill_cache(
@@ -166,9 +172,9 @@ def prefill_fill_cache(
     cap: int,
     dtype,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Build a decode cache from right-padded (bucketed) prefill K/V.
+    """Build a decode cache from prefill K/V (right-padded when bucketed).
 
-    ``k_new``/``v_new``: (B, S, K, D) over the padded sequence; ``lengths``
+    ``k_new``/``v_new``: (B, S, K, D) over the (padded) sequence; ``lengths``
     (B,) gives each row's real prompt length.  For cache slot ``j`` the winner
     is the LAST real position ``p < lengths`` with ``p % cap == j`` (ring
     semantics, gather-based so per-row variable lengths never produce
@@ -295,32 +301,38 @@ def write_pages(
     v_new: jax.Array,
     block_tables: jax.Array,
     start_pos: jax.Array,
+    layer: jax.Array,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Scatter T new KV entries into a global page pool via block tables.
+    """Scatter layer ``layer``'s T new KV entries into the stacked page pools
+    via block tables, in place.
 
-    pool_k/v: (n_pages, K, ps, D) — head-major, so one head of one page is a
-    contiguous (ps, D) tile for the paged decode kernel; k/v_new: (B, T, K,
-    D); block_tables: (B, P) page indices (-1 = unallocated); start_pos:
-    (B,).  Position ``p`` of row ``b`` lands in slot ``p % ps`` of page
-    ``block_tables[b, p // ps]`` — positions are written exactly once (no
-    ring wrap; the block table is sized for the full context), so the paged
-    decode mask can reconstruct positions from page indices alone.  Writes
-    whose page entry is missing (or beyond the table) drop: inactive rows and
-    bucket padding never touch live pages.
+    pool_k/v: (L, n_pages, K, ps, D) — every layer's pool; head-major, so one
+    head of one page is a contiguous (ps, D) tile for the paged decode
+    kernel; k/v_new: (B, T, K, D); block_tables: (B, P) page indices (-1 =
+    unallocated); start_pos: (B,).  Position ``p`` of row ``b`` lands in slot
+    ``p % ps`` of page ``block_tables[b, p // ps]`` of layer ``layer`` —
+    positions are written exactly once (no ring wrap; the block table is
+    sized for the full context), so the paged decode mask can reconstruct
+    positions from page indices alone.  Writes whose page entry is missing
+    (or beyond the table) drop: inactive rows and bucket padding never touch
+    live pages.
     """
-    n_pages, K, ps, D = pool_k.shape
+    _, n_pages, K, ps, D = pool_k.shape
     B, T = k_new.shape[:2]
     P = block_tables.shape[1]
     pos = start_pos[:, None] + jnp.arange(T)[None, :]          # (B, T)
     pidx = pos // ps
     page = jnp.take_along_axis(block_tables, jnp.clip(pidx, 0, P - 1), axis=1)
     page = jnp.where((pidx < P) & (page >= 0), page, n_pages)  # OOB drops
-    page = page.reshape(B * T)
-    slot = (pos % ps).reshape(B * T)
-    kf = pool_k.at[page, :, slot].set(
+    # one index per (row, token, head): the scatter window is a single
+    # contiguous D vector, so the pool keeps its layout through the update
+    page = page.reshape(B * T, 1)
+    slot = (pos % ps).reshape(B * T, 1)
+    head = jnp.arange(K)[None, :]
+    kf = pool_k.at[layer, page, head, slot].set(
         k_new.reshape(B * T, K, D).astype(pool_k.dtype), mode="drop"
     )
-    vf = pool_v.at[page, :, slot].set(
+    vf = pool_v.at[layer, page, head, slot].set(
         v_new.reshape(B * T, K, D).astype(pool_v.dtype), mode="drop"
     )
     return kf, vf
@@ -332,16 +344,20 @@ def attention_decode(
     x: jax.Array,
     cache: dict,
     cache_len: jax.Array,
+    layer: jax.Array,
     block_tables: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, dict]:
     """Decode T new tokens (T >= 1 for speculative verification).
 
-    ``cache`` = {"k", "v", "kv_pos"}; ``cache_len`` (B,) is the committed
-    length BEFORE these tokens.  Query i sits at absolute position
-    cache_len + i.  With ``block_tables`` the cache is instead the global
-    page pool {"k", "v"}: (n_pages, K, ps, D) — writes and attention go
+    ``cache`` = {"k", "v", "kv_pos"} stacked over the layers of the stack,
+    (L, B, cap, ...); this call is layer ``layer``.  ``cache_len`` (B,) is the
+    committed length BEFORE these tokens; query i sits at absolute position
+    cache_len + i.  With ``block_tables`` the cache is instead the stacked
+    page pools {"k", "v"}: (L, n_pages, K, ps, D) — writes and attention go
     through the per-row tables (paged layout; requires full attention, the
-    engine gates SWA off).
+    engine gates SWA off).  The T new rows are scattered into the stacked
+    buffers in place; the kernel then reads layer ``layer`` as a slice of
+    them.  Returns (output, updated stacked cache).
     """
     B, T, _ = x.shape
     q = _project_q(p, cfg, x)
@@ -350,30 +366,45 @@ def attention_decode(
     q = rope(q, pos, cfg.rope_theta)
     k = rope(k, pos, cfg.rope_theta)
 
+    def view(a):
+        return jax.lax.dynamic_index_in_dim(a, layer, keepdims=False)
+
     if block_tables is not None:
-        ck, cv = write_pages(cache["k"], cache["v"], k, v, block_tables, cache_len)
+        ck, cv = write_pages(cache["k"], cache["v"], k, v, block_tables,
+                             cache_len, layer)
         out = ops.decode_attention_paged(
-            q, ck, cv, cache_len + T, block_tables, window=cfg.sliding_window
+            q, view(ck), view(cv), cache_len + T, block_tables,
+            window=cfg.sliding_window,
         )
         out = _project_out(p, cfg, out, "bthe,hed->btd")
         return out, {"k": ck, "v": cv}
 
     # context-parallel path: sequence-sharded KV, LSE-merged (see
     # _decode_attention_cp); ring-buffer (SWA) caches shard the same way,
-    # with the window folded into the position mask.
+    # with the window folded into the position mask.  It works on the
+    # layer's slice and writes that slice back into the stack.
     mesh = _cp_mesh()
     if mesh is not None:
-        res = _decode_attention_cp(mesh, cfg, q, k, v, cache, cache_len)
+        res = _decode_attention_cp(
+            mesh, cfg, q, k, v, {n: view(a) for n, a in cache.items()}, cache_len
+        )
         if res is not None:
-            out, new_cache = res
+            out, new_layer = res
             out = _project_out(p, cfg, out, "bthe,hed->btd")
-            return out, new_cache
+            return out, {
+                n: jax.lax.dynamic_update_index_in_dim(a, new_layer[n], layer, 0)
+                for n, a in cache.items()
+            }
 
-    ck, cv, cp = write_cache(cache["k"], cache["v"], cache["kv_pos"], k, v, cache_len)
-    ck = constraint(ck, ("batch", "kv_seq", "kv", None))
-    cv = constraint(cv, ("batch", "kv_seq", "kv", None))
+    ck, cv, cp = write_cache(cache["k"], cache["v"], cache["kv_pos"], k, v,
+                             cache_len, layer)
     out = ops.decode_attention(
-        q, ck, cv, cache_len + T, kv_positions=cp, window=cfg.sliding_window
+        q,
+        constraint(view(ck), ("batch", "kv_seq", "kv", None)),
+        constraint(view(cv), ("batch", "kv_seq", "kv", None)),
+        cache_len + T,
+        kv_positions=view(cp),
+        window=cfg.sliding_window,
     )
     out = _project_out(p, cfg, out, "bthe,hed->btd")
     return out, {"k": ck, "v": cv, "kv_pos": cp}

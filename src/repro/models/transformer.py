@@ -157,11 +157,14 @@ def block_prefill(
     shape bucket and only the first ``lengths[b]`` positions of row ``b`` are
     real.  Causal attention already makes real positions independent of the
     trailing padding; the cache is seeded through the gather-based
-    ``prefill_fill_cache`` so padded slots stay invisible (``kv_pos = -1``).
+    ``prefill_fill_cache`` (every row's full length when unbucketed; a ring
+    buffer smaller than the prompt keeps the tail) so padded slots stay
+    invisible (``kv_pos = -1``).
     Attention-only stacks only — SSM recurrent state cannot ignore a padded
     suffix, so callers gate bucketing on the architecture.
     """
-    S = x.shape[1]
+    B, S = x.shape[:2]
+    lens = lengths if lengths is not None else jnp.full((B,), S, jnp.int32)
     new_cache: Dict[str, Any] = {}
     for i in range(cfg.scan_block):
         layer = params[str(i)]
@@ -170,18 +173,7 @@ def block_prefill(
             out, (k, v) = attn.attention_prefill(layer["attn"], cfg, h, positions)
             x = x + out
             c = cache[str(i)]
-            cap = c["k"].shape[1]
-            start = jnp.zeros((x.shape[0],), jnp.int32)
-            if lengths is not None:
-                ck, cv, cp = attn.prefill_fill_cache(k, v, lengths, cap, c["k"].dtype)
-            elif cap >= S:
-                ck, cv, cp = attn.write_cache(c["k"], c["v"], c["kv_pos"], k, v, start)
-            else:  # ring buffer smaller than the prompt: keep the tail
-                tail = S - cap
-                ck, cv, cp = attn.write_cache(
-                    c["k"], c["v"], c["kv_pos"], k[:, tail:], v[:, tail:],
-                    start + tail,
-                )
+            ck, cv, cp = attn.prefill_fill_cache(k, v, lens, c["k"].shape[1], c["k"].dtype)
             nc = {"k": ck, "v": cv, "kv_pos": cp}
         else:
             if lengths is not None:
@@ -208,44 +200,53 @@ def block_decode(
     cfg: ArchConfig,
     x: jax.Array,
     aux: Dict,
+    kv: Dict[str, Any],
     cache: Dict[str, Any],
     cache_len: jax.Array,
+    block_idx: jax.Array,
     *,
     mem_len: Optional[jax.Array] = None,
     block_tables: Optional[jax.Array] = None,
-) -> Tuple[jax.Array, Dict, Dict[str, Any]]:
-    """Decode T tokens through one block, updating its cache.
+) -> Tuple[jax.Array, Dict, Dict[str, Any], Dict[str, Any]]:
+    """Decode T tokens through block ``block_idx`` of the stack.
 
-    Cross memories (enc-dec) live in the cache ("cross_k"/"cross_v"),
-    precomputed at prefill; ``mem_len`` gives their valid length.  With
-    ``block_tables`` the attn caches are global page pools ({"k", "v"} only).
+    ``kv`` holds each attention layer position's KV cache stacked over all
+    blocks (leading axis n_blocks): {"k", "v", "kv_pos"}, or with
+    ``block_tables`` the global page pools {"k", "v"}.  The layer writes its
+    T new rows into it in place at ``block_idx`` and reads its own slice for
+    attention.  ``cache`` holds this block's other per-layer state: SSM
+    ``conv``/``state`` and the cross memories (enc-dec, "cross_k"/"cross_v"),
+    precomputed at prefill, with ``mem_len`` their valid length.
+
+    Returns (x, aux, kv', new) — ``new`` holds each position's rewritten
+    per-layer state (SSM state and its per-position rollback copies); the
+    read-only cross memories are not re-emitted.
     """
+    kv = dict(kv)
     new_cache: Dict[str, Any] = {}
     for i in range(cfg.scan_block):
         layer = params[str(i)]
         h = rms_norm(x, layer["norm1"], cfg.norm_eps)
         c = cache[str(i)]
+        nc: Dict[str, Any] = {}
         if "attn" in layer:
-            keys = ("k", "v") if block_tables is not None else ("k", "v", "kv_pos")
-            out, nc = attn.attention_decode(
-                layer["attn"], cfg, h, {k: c[k] for k in keys}, cache_len,
+            out, kv[str(i)] = attn.attention_decode(
+                layer["attn"], cfg, h, kv[str(i)], cache_len, block_idx,
                 block_tables=block_tables,
             )
-            x = x + out
         else:
             out, nc = ssm.mamba_decode(
                 layer["mamba"], cfg, h, {k: c[k] for k in ("conv", "state")}
             )
-            x = x + out
+        x = x + out
         if "cross" in layer:
             hc = rms_norm(x, layer["norm_cross"], cfg.norm_eps)
             x = x + attn.attention_cross(
                 layer["cross"], cfg, hc, c["cross_k"], c["cross_v"], mem_len
             )
-            nc = dict(nc, cross_k=c["cross_k"], cross_v=c["cross_v"])
         new_cache[str(i)] = nc
         x, aux = _apply_ffn(layer, cfg, x, aux)
-    return x, aux, new_cache
+    return x, aux, kv, new_cache
 
 
 def commit_block_cache(cache: Dict[str, Any], accept_idx: jax.Array) -> Dict[str, Any]:
@@ -307,14 +308,41 @@ def scan_prefill(stacked, cfg: ArchConfig, x, positions, cache, *, cross_mem=Non
     return x, aux, new_cache
 
 
+# Leaves of a layer's cache that decode writes at T positions per step: the
+# attention KV rows (or page pools) and their slot positions.
+_IN_PLACE_LEAVES = ("k", "v", "kv_pos")
+
+
 def scan_decode(stacked, cfg: ArchConfig, x, cache, cache_len, *, mem_len=None,
                 block_tables=None):
-    def body(carry, inp):
-        x, aux = carry
-        bp, bc = inp
-        x, aux, nc = block_decode(bp, cfg, x, aux, bc, cache_len, mem_len=mem_len,
-                                  block_tables=block_tables)
-        return (x, aux), nc
+    """Decode T tokens through every block of the stack.
 
-    (x, aux), new_cache = jax.lax.scan(body, (x, dict(AUX0)), (stacked, cache))
-    return x, new_cache
+    The cache is split by what the step does to each leaf.  Leaves written at
+    T positions (``_IN_PLACE_LEAVES``: dense K/V rows and slot positions, or
+    the page pools) ride in the scan carry whole, so each layer scatters its
+    new rows into the one stacked buffer at its block index: no per-layer
+    slab is re-stacked as a scan output and no second cache-sized buffer
+    exists.  Every other leaf — SSM ``conv``/``state`` (rewritten whole,
+    small) and the read-only cross memories — is scanned as a per-block
+    slice; only the rewritten SSM state comes back as stacked outputs.  What
+    is not in place: each attention layer still reads its layer out of the
+    stack as a slice for the decode kernel (and the dense kernel transposes
+    it head-major).
+    """
+    kv = {n: {k: a for k, a in c.items() if k in _IN_PLACE_LEAVES}
+          for n, c in cache.items()}
+    per_block = {n: {k: a for k, a in c.items() if k not in _IN_PLACE_LEAVES}
+                 for n, c in cache.items()}
+
+    def body(carry, inp):
+        x, aux, kv = carry
+        idx, bp, bc = inp
+        x, aux, kv, nc = block_decode(bp, cfg, x, aux, kv, bc, cache_len, idx,
+                                      mem_len=mem_len, block_tables=block_tables)
+        return (x, aux, kv), nc
+
+    n_blocks = cfg.n_layers // cfg.scan_block
+    (x, _, kv), rewritten = jax.lax.scan(
+        body, (x, dict(AUX0), kv), (jnp.arange(n_blocks), stacked, per_block)
+    )
+    return x, {n: {**cache[n], **kv[n], **rewritten[n]} for n in cache}

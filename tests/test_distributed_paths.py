@@ -89,7 +89,8 @@ SCRIPT = textwrap.dedent(
     cache = {"k": ck, "v": cv, "kv_pos": cp}
 
     # reference: plain write + ref decode attention
-    ck2, cv2, cp2 = A.write_cache(ck, cv, cp, kn, vn, clen)
+    ck2, cv2, cp2 = (a[0] for a in A.write_cache(ck[None], cv[None], cp[None],
+                                                 kn, vn, clen, 0))
     want = R.decode_attention(q, ck2, cv2, clen + T, kv_positions=cp2)
 
     with jax.set_mesh(mesh):
@@ -108,6 +109,36 @@ SCRIPT = textwrap.dedent(
     out["cp_attn_err"] = float(jnp.abs(want - got_out).max())
     out["cp_cache_err"] = float(jnp.abs(jnp.sort(new_cache["kv_pos"], -1)
                                         - jnp.sort(cp2, -1)).max())
+
+    # the same path inside attention_decode on a two-layer stack: layer 1's
+    # slice goes through the CP path and is written back into the stack
+    fcfg = dataclasses.replace(acfg, dtype="float32")
+    pa = A.init_attention(jax.random.PRNGKey(2), fcfg)
+    pa = jax.tree.map(lambda q: q.value if hasattr(q, "value") else q, pa,
+                      is_leaf=lambda x: hasattr(x, "value"))
+    xa = jnp.asarray(rng.normal(size=(B, T, fcfg.d_model)) * 0.5, jnp.float32)
+    stack = {"k": jnp.stack([ck * 0.5, ck]), "v": jnp.stack([cv * 0.5, cv]),
+             "kv_pos": jnp.stack([cp, cp])}
+    want_o, want_c = A.attention_decode(pa, fcfg, xa, stack, clen, 1)
+    with jax.set_mesh(mesh):
+        specs = {"k": PS(None, "data", "model", None, None),
+                 "v": PS(None, "data", "model", None, None),
+                 "kv_pos": PS(None, "data", "model")}
+        stack_d = {n: jax.device_put(a, NamedSharding(mesh, specs[n]))
+                   for n, a in stack.items()}
+        got_o, got_c = jax.jit(
+            lambda xx, cc, ll: A.attention_decode(pa, fcfg, xx, cc, ll, 1)
+        )(xa, stack_d, cl)
+    out["cp_stack_attn_err"] = float(
+        jnp.abs(want_o - got_o).max() / jnp.abs(want_o).max()
+    )
+    out["cp_stack_kv_err"] = max(
+        float(jnp.abs(want_c[n] - got_c[n]).max()) for n in ("k", "v")
+    )
+    out["cp_stack_pos_equal"] = bool((want_c["kv_pos"] == got_c["kv_pos"]).all())
+    out["cp_stack_layer0_kept"] = all(
+        bool((got_c[n][0] == stack[n][0]).all()) for n in stack
+    )
 
     # ---------------- hierarchical all-reduce ----------------
     from repro.distributed.collectives import hierarchical_all_reduce
@@ -154,6 +185,13 @@ def test_moe_capacity_split_matches_global(results):
 def test_context_parallel_decode_matches_ref(results):
     assert results["cp_attn_err"] < 1e-4, results
     assert results["cp_cache_err"] == 0.0, results
+
+
+def test_context_parallel_decode_writes_back_into_stack(results):
+    assert results["cp_stack_attn_err"] < 1e-5, results
+    assert results["cp_stack_kv_err"] < 1e-5, results
+    assert results["cp_stack_pos_equal"], results
+    assert results["cp_stack_layer0_kept"], results
 
 
 def test_hierarchical_all_reduce(results):

@@ -157,3 +157,24 @@ def test_adaptive_depth_responds_to_acceptance(small_model):
     eng.run_until_done(max_steps=800)
     d = eng.pairs[0].spec.last_decision
     assert d is not None and d.bucket_depth >= 2
+
+
+def test_reset_cache_reuses_the_old_buffers(small_model):
+    """Warm-up ends with ``reset_cache``: the empty cache is written into the
+    donated old one, never allocated beside it (the old buffers outlive the
+    steps queued on them, so both would share the device)."""
+    from repro.core.engine import ModelLane
+
+    cfg, params = small_model
+    for paged in (False, True):
+        lane = ModelLane(cfg, params, 2, 32, paged=paged, kv_blocks=8,
+                         kv_block_size=8)
+        lane.decode(jax.numpy.ones((2, 3), jax.numpy.int32))
+        old = jax.tree.leaves(lane.cache)
+        lane.reset_cache()
+        assert all(a.is_deleted() for a in old)
+        init, sizes = lane._cache_init()
+        fresh = init(*sizes)
+        for got, want in zip(jax.tree.leaves(lane.cache), jax.tree.leaves(fresh),
+                             strict=True):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

@@ -8,6 +8,9 @@ is described inside a module fixture — never at import — so only the worker
 that runs this file loads libtpu, and every worker collects the same tests.
 All compile-only tests stay in this one file for that reason.
 """
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -96,10 +99,10 @@ def test_flash_attention_compiles(one_chip):
     ))
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
-def test_engine_verify_step_compiles(one_chip, monkeypatch, paged):
-    """One whole verify step (T = 9) of a 2048-token, 16-slot lane at full
-    width: the decode kernel sits inside the compiled program."""
+def _lane_decode_lowered(one_chip, monkeypatch, paged, T):
+    """``engine._lane_decode`` of a 2048-token, 16-slot lane at full width,
+    lowered for the described chip; returns (lowered, params, cache) with
+    params and cache as placed shapes."""
     monkeypatch.setattr(ops, "_use_pallas", lambda: True)  # host backend is CPU
     model = build_model(CFG)
 
@@ -113,6 +116,64 @@ def test_engine_verify_step_compiles(one_chip, monkeypatch, paged):
         cache = placed(lambda: model.init_paged_cache(B, N_PAGES, PAGE, S))
     else:
         cache = placed(lambda: model.init_cache(B, S))
-    _assert_kernel(engine._lane_decode.lower(
-        model.decode_step, params, cache, _sds(one_chip, (B, 9), jnp.int32)
-    ))
+    lowered = engine._lane_decode.lower(
+        model.decode_step, params, cache, _sds(one_chip, (B, T), jnp.int32)
+    )
+    return lowered, params, cache
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_engine_verify_step_compiles(one_chip, monkeypatch, paged):
+    """One whole verify step (T = 9) of a 2048-token, 16-slot lane at full
+    width: the decode kernel sits inside the compiled program."""
+    _assert_kernel(_lane_decode_lowered(one_chip, monkeypatch, paged, 9)[0])
+
+
+_INSTR = re.compile(r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\((.*)$")
+
+
+def _whole_cache_moves(hlo: str, stacked):
+    """Instructions of an optimized HLO module that copy a whole stacked
+    cache leaf, or write a whole layer of one back with a
+    dynamic-update-slice."""
+    shapes, moves = {}, []
+    for line in hlo.splitlines():
+        m = _INSTR.match(line)
+        if m is None:
+            continue
+        name, dims, op, operands = m.groups()
+        shape = tuple(int(d) for d in dims.split(",") if d)
+        shapes[name] = shape
+        if shape not in stacked:
+            continue
+        if op in ("copy", "copy-start"):
+            moves.append(line.strip())
+        elif op == "dynamic-update-slice":
+            update = re.findall(r"%([^\s,)]+)", operands)[1]
+            if math.prod(shapes[update]) == math.prod(shape[1:]):
+                moves.append(line.strip())
+    return moves
+
+
+@pytest.mark.parametrize("T", [1, 9])
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_engine_decode_keeps_kv_in_place(one_chip, monkeypatch, paged, T):
+    """The decode step writes its new KV rows into the donated stacked cache:
+    the compiled program holds no copy of a whole stacked K/V leaf (the
+    scan's re-stacked outputs copied into the donated buffer) and no
+    dynamic-update-slice of a whole layer (the scan's per-layer write-back),
+    and every cache leaf is aliased input to output."""
+    lowered, params, cache = _lane_decode_lowered(one_chip, monkeypatch, paged, T)
+    hlo = lowered.compile().as_text()
+    assert "tpu_custom_call" in hlo
+    stacked = {a.shape for a in jax.tree.leaves(cache["blocks"])}
+    assert stacked == ({(CFG.n_layers, N_PAGES, K, PAGE, D)} if paged else
+                       {(CFG.n_layers, B, S, K, D), (CFG.n_layers, B, S)})
+    assert _whole_cache_moves(hlo, stacked) == []
+    # flat argument order: params, cache, tokens
+    n_params = len(jax.tree.leaves(params))
+    cache_args = set(range(n_params, n_params + len(jax.tree.leaves(cache))))
+    aliased = {int(a) for a in re.findall(
+        r"\{[\d,]*\}: \((\d+), \{\}, (?:may|must)-alias\)", hlo
+    )}
+    assert cache_args <= aliased, sorted(cache_args - aliased)
