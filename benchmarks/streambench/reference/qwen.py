@@ -1,4 +1,4 @@
-"""Plain float32 forward pass of the Qwen2 and Qwen3 decoders.
+"""Plain float32 forward pass of the Qwen2 and Qwen3 decoders (the ``qwen`` family).
 
 It follows the published modelling code (Hugging Face ``modeling_qwen2`` and
 ``modeling_qwen3``): pre-norm RMSNorm blocks, rotary embeddings on the two
@@ -8,7 +8,7 @@ normalises each query and key head (``q_norm``, ``k_norm``) before the
 rotation; Qwen2 adds a bias to the q, k and v projections.
 
 It imports nothing of the program under test.  Weights come in the layout of
-``sbench/weights.py``: matrices ``(in, out)``, layers stacked on the first
+``families/qwen.py``: matrices ``(in, out)``, layers stacked on the first
 axis.  Every matrix product runs at ``Precision.HIGHEST``, so a TPU computes
 it in float32.  One sequence at a time, layer by layer, so that it fits
 beside the weights after the program has been freed.

@@ -1,17 +1,17 @@
-"""Operations and bytes the served work needs, from shapes alone.
+"""What every family's counts of operations and bytes share.
 
-Only live work counts: rows that hold a request, each row's own cache length,
-and the tokens each row actually feeds.  Padded rows, padded verify depth and
-padded prompt positions are left out, and so is the allocated cache length.
-A kernel that skips work it does not need therefore reads better, and a
-share of the roofline computed from these counts cannot pass 100% unless the
-kernel time leaves out part of the work.
+A family module (``families/<family>.py``) counts the FLOPs and bytes of its
+own calls from shapes alone, and only live work: rows that hold a request,
+each row's own cache length, and the tokens each row actually feeds.  Padded
+rows, padded verify depth, padded heads and padded prompt positions are left
+out, and so is the allocated cache length.  A kernel that skips work it does
+not need therefore reads better, and a share of the roofline computed from
+these counts cannot pass 100% unless the kernel time leaves out part of the
+work.
 """
 from __future__ import annotations
 
-from typing import Iterable, Tuple
-
-from sbench.weights import Dims
+from typing import Any, Callable, Dict, Iterable, List, Tuple
 
 BF16 = 2  # bytes per element served
 
@@ -21,50 +21,23 @@ def attn_pairs(cached: int, fed: int) -> int:
     return fed * cached + fed * (fed + 1) // 2
 
 
-def decode_attention_cost(m: Dims, rows: Iterable[Tuple[int, int]]) -> Tuple[float, float]:
-    """FLOPs and bytes of one decode-attention call (one layer) over live
-    ``rows`` of ``(cached_len, fed)``: QK^T and PV, reading each row's live
-    keys and values once, reading q and writing o."""
-    flops = nbytes = 0.0
-    for cached, fed in rows:
-        flops += 4.0 * m.H * m.D * attn_pairs(cached, fed)
-        nbytes += BF16 * m.D * (2 * m.K * (cached + fed) + 2 * m.H * fed)
-    return flops, nbytes
-
-
-def flash_attention_cost(m: Dims, prompt_lens: Iterable[int]) -> Tuple[float, float]:
-    """FLOPs and bytes of one causal prefill-attention call (one layer) over
-    the live prompt lengths of its rows."""
-    flops = nbytes = 0.0
-    for n in prompt_lens:
-        flops += 4.0 * m.H * m.D * attn_pairs(0, n)
-        nbytes += BF16 * m.D * n * (2 * m.H + 2 * m.K)
-    return flops, nbytes
-
-
-def linear_params(m: Dims) -> int:
-    """Matrix parameters of one layer (q, k, v, o and the SwiGLU MLP)."""
-    return m.d * m.D * (2 * m.H + 2 * m.K) + 3 * m.d * m.f
-
-
-def decode_step_flops(m: Dims, rows: Iterable[Tuple[int, int]]) -> float:
-    """Model FLOPs one decode/verify call needs: every fed token goes through
-    every layer and the output head; attention over each row's live cache."""
-    rows = list(rows)
-    fed = sum(f for _, f in rows)
-    attn, _ = decode_attention_cost(m, rows)
-    return m.n_layers * (2.0 * linear_params(m) * fed + attn) + 2.0 * m.d * m.V * fed
-
-
-def prefill_flops(m: Dims, prompt_lens: Iterable[int]) -> float:
-    """Model FLOPs one prefill call needs: every prompt token through every
-    layer, causal attention, and the output head at each row's last token."""
-    lens = list(prompt_lens)
-    attn, _ = flash_attention_cost(m, lens)
-    return (m.n_layers * (2.0 * linear_params(m) * sum(lens) + attn)
-            + 2.0 * m.d * m.V * len(lens))
-
-
 def least_time(flops: float, nbytes: float, peak_flops: float, peak_bw: float) -> float:
     """Seconds the chip needs at the least: the larger of the two bounds."""
     return max(flops / peak_flops, nbytes / peak_bw)
+
+
+def kernel_needs(cost: Callable[[Dict[str, Any], Any], Tuple[int, float, float]],
+                 cfg: Dict[str, Any], works: Iterable[Any],
+                 peaks: Dict[str, float]) -> List[Tuple[int, float]]:
+    """Least device seconds of one kernel's calls over the program executions
+    whose work (a decode call's live rows, a prefill call's prompt lengths) is
+    in ``works``.  ``cost(cfg, work)`` gives one execution's ``(calls, FLOPs
+    of one call, bytes of one call)``.  Returns ``(calls per execution, summed
+    least time of one call)`` for each number of calls met; the kernel's least
+    time is the sum of ``calls * seconds``."""
+    pf, bw = peaks["bf16_flops_per_s"], peaks["hbm_bytes_per_s"]
+    sums: Dict[int, float] = {}
+    for work in works:
+        calls, flops, nbytes = cost(cfg, work)
+        sums[calls] = sums.get(calls, 0.0) + least_time(flops, nbytes, pf, bw)
+    return list(sums.items())

@@ -304,20 +304,18 @@ def pick_sample(drv: Driver, seed: int, ref_tokens: int, max_requests: int) -> L
     return out
 
 
-def reference_gaps(cfg: Dict[str, Any], mix: Dict[str, Any], seed: int,
+def reference_gaps(cell: specmod.Cell, seed: int,
                    sample: List[Tuple[List[int], List[int]]],
                    control: Optional[str] = None):
-    """Per request, at each served position, the reference's best logit minus
-    its logit of the served token.  With ``control``, also the same gap for
-    the token that the lower-precision control puts first there.  Returns
-    ``(served_gaps, control_gaps or None)``, one array per request."""
+    """Per request, at each served position, the family's reference's best
+    logit minus its logit of the served token.  With ``control``, also the
+    same gap for the token that the lower-precision control puts first there.
+    Returns ``(served_gaps, control_gaps or None)``, one array per request."""
     import jax
     import jax.numpy as jnp
 
-    from reference import qwen
-    from sbench.weights import make_weights
-
-    w = make_weights(cfg, seed)
+    cfg, mix, ref_mod = cell.config, cell.traffic, cell.reference
+    w = cell.family.make_weights(cfg, seed)
     n_pos = int(mix["answer"]["max"])
     L = -(-(int(mix["prompt"]["max"]) + n_pos) // 128) * 128
     served_gaps, control_gaps = [], []
@@ -326,7 +324,7 @@ def reference_gaps(cfg: Dict[str, Any], mix: Dict[str, Any], seed: int,
         seq = list(prompt) + list(served)
         toks[: len(seq)] = seq
         start = len(prompt) - 1
-        ref = qwen.logits_at(cfg, w, toks, start, n_pos)
+        ref = ref_mod.logits_at(cfg, w, toks, start, n_pos)
         best = ref.max(-1)
 
         def gap(pick):
@@ -335,7 +333,7 @@ def reference_gaps(cfg: Dict[str, Any], mix: Dict[str, Any], seed: int,
 
         served_gaps.append(gap(jnp.asarray(np.pad(served, (0, n_pos - len(served))), jnp.int32)))
         if control is not None:
-            low = qwen.logits_at(cfg, w, toks, start, n_pos, quant=control)
+            low = ref_mod.logits_at(cfg, w, toks, start, n_pos, quant=control)
             control_gaps.append(gap(jnp.argmax(low, -1).astype(jnp.int32)))
     del w
     return served_gaps, (control_gaps if control is not None else None)
@@ -378,10 +376,10 @@ def run(cell: specmod.Cell, seed: int, seconds: float, trace: bool, t_start: flo
         f"device={dev} cache={cache} requests_planned={len(plan)} rate_per_s={rate}")
     t = phase("jax_init", t)
 
-    from sbench.weights import make_weights
-    w = jax.block_until_ready(make_weights(cfg, seed))
+    family = cell.family
+    w = jax.block_until_ready(family.make_weights(cfg, seed))
     t = phase("weights", t)
-    serve = program.build(cfg, w)
+    serve = program.build(family, cfg, w)
     del w
     t = phase("engine", t)
     programs = serve.engine.warmup(max_prompt_len=int(mix["prompt"]["max"]))
@@ -415,6 +413,7 @@ def run(cell: specmod.Cell, seed: int, seconds: float, trace: bool, t_start: flo
     log("streambench setup: " + " ".join(f"{k}={v:.3f}s" for k, v in phases)
         + f" programs_warmed={programs} total={setup_s:.3f}s")
     if trace:
+        counts0 = program_counters(serve)
         rec.on = True
         drv.spec = [0, 0, 0]
         drv._seen = {q.i: (len(q.r.output_tokens), len(q.r.spec_depths)) for q in drv.live}
@@ -423,6 +422,7 @@ def run(cell: specmod.Cell, seed: int, seconds: float, trace: bool, t_start: flo
             drv.sync()
         rec.on = False
         spec_counts, drv.spec = drv.spec, None
+        counts = counter_diff(counts0, program_counters(serve))
         jax.profiler.stop_trace()
     t_close = drv.drive(w0 + seconds)
     drv.sync()
@@ -431,7 +431,7 @@ def run(cell: specmod.Cell, seed: int, seconds: float, trace: bool, t_start: flo
     mem_close = memory()
     dev["memory_peak_bytes"] = mem_close["peak"]
     kv_tok = drv.kv_tokens
-    kv_per_tok = kv_bytes_per_token(cfg)
+    kv_per_tok = family.kv_bytes_per_token(cfg)
     log(f"streambench memory: after warm-up in_use={mem_warm['in_use']} "
         f"peak={mem_warm['peak']}; window open in_use={mem_open['in_use']} "
         f"peak={mem_open['peak']}; close in_use={mem_close['in_use']} "
@@ -452,10 +452,12 @@ def run(cell: specmod.Cell, seed: int, seconds: float, trace: bool, t_start: flo
         shutil.rmtree(trace_dir, ignore_errors=True)
         dev["busy_s"], dev["window_s"] = red.busy_s, red.window_s
         # what a per-layer metric reader (metrics/<name>.py) sees
-        ctx = SimpleNamespace(cell=cell, cfg=cfg, trace=red, decode_calls=rec.decode,
-                              prefill_calls=rec.prefill, spec=spec_counts,
-                              host=window_stats(drv, w0, t_traced),
-                              peaks=load_peaks(dev["kind"]))
+        ctx = SimpleNamespace(cell=cell, cfg=cfg, family=family, trace=red,
+                              decode_calls=rec.decode, prefill_calls=rec.prefill,
+                              spec=spec_counts, host=window_stats(drv, w0, t_traced),
+                              peaks=load_peaks(dev["kind"]), counters=counts,
+                              requests=[q.r for q in drv.reqs if w0 <= q.due < t_traced],
+                              close=t_traced)
         for m in cell.per_layer:
             v = specmod.metric_reader(m["name"], cell.metrics_dir)(ctx)
             if v is not None:
@@ -467,7 +469,8 @@ def run(cell: specmod.Cell, seed: int, seconds: float, trace: bool, t_start: flo
                 "value": end_to_end(m["name"], stats, setup_s), "unit": m["unit"]}
 
     # ---- check: free the program, then the reference over a sample
-    sample = pick_sample(drv, seed, int(mix["ref_tokens"]), int(mix["ref_max_requests"]))
+    sample = pick_sample(drv, seed, int(mix["ref_tokens"]),
+                         int(mix["ref_max_requests"]))
     pairs = [(list(q.r.prompt), list(q.r.output_tokens)) for q in sample]
     mismatched = sum(1 for q in drv.reqs if q.r.state.value == "finished"
                      and len(q.r.output_tokens) != q.r.params.max_new_tokens)
@@ -476,7 +479,7 @@ def run(cell: specmod.Cell, seed: int, seconds: float, trace: bool, t_start: flo
     del serve, drv, sample, rec
     gc.collect()
     t_ref = clock()
-    gaps, cgaps = reference_gaps(cfg, mix, seed, pairs, control)
+    gaps, cgaps = reference_gaps(cell, seed, pairs, control)
     gap = widest(gaps)
     n_cmp = int(sum(len(g) for g in gaps))
     limit = data.get("max_logit_gap")
@@ -501,12 +504,20 @@ def run(cell: specmod.Cell, seed: int, seconds: float, trace: bool, t_start: flo
     return result
 
 
-def kv_bytes_per_token(cfg: Dict[str, Any]) -> int:
-    """Bytes of bf16 keys and values one cached token holds over all layers."""
-    from sbench.weights import Dims
+def program_counters(serve) -> Optional[Dict[str, Any]]:
+    """The program's own work counters (``engine.counters()``), or None where
+    the program keeps none."""
+    counters = getattr(serve.engine, "counters", None)
+    return None if counters is None else counters()
 
-    m = Dims.of(cfg)
-    return 2 * m.n_layers * m.K * m.D * 2
+
+def counter_diff(before: Optional[Dict[str, Any]],
+                 after: Optional[Dict[str, Any]]) -> Optional[Dict[str, int]]:
+    """The program's work counters over an interval: each total's growth
+    (summed over the pairs; the per-pair ``"pairs"`` left out)."""
+    if before is None or after is None:
+        return None
+    return {k: after[k] - before[k] for k in after if k != "pairs"}
 
 
 def load_peaks(kind: str) -> Dict[str, Any]:

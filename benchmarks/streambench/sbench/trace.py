@@ -2,10 +2,11 @@
 
 ``load_xspace`` reads the ``.xplane.pb`` that ``jax.profiler`` writes and keeps
 only what the reduction needs: the TPU planes' ``XLA Ops`` and ``XLA Modules``
-lines, and the harness's own host spans (names starting ``sb.``).  The
-result is plain data (lists of ``[name, start_ns, dur_ns, stats]``), so a small
-recorded trace can be kept as a test fixture.  ``Reduced`` does the
-arithmetic on that data:
+lines, the harness's own host spans (names starting ``sb.``) in the host
+plane's lines, and, in a list of their own under ``"program"``, the program's
+host spans (names starting ``ss.``).  The result is plain data (lists of
+``[name, start_ns, dur_ns, stats]``), so a small recorded trace can be kept as
+a test fixture.  ``Reduced`` does the arithmetic on that data:
 
 - the traced window is the host span ``sb.window``;
 - busy time is the union of the device op intervals inside it, averaged over
@@ -14,7 +15,8 @@ arithmetic on that data:
   starts with it (the Pallas kernel's ``name=``, as in ``%decode_attention.6``);
 - a program's executions are the ``XLA Modules`` events whose name holds the
   jitted function's name;
-- each idle gap is labelled by the innermost harness span around its middle.
+- each idle gap is labelled by the innermost harness span around its middle;
+- a span's idle time is the device idle time inside its interval.
 """
 from __future__ import annotations
 
@@ -24,6 +26,7 @@ from collections import defaultdict
 from typing import Any, Dict, List, Tuple
 
 DEVICE_LINES = ("XLA Ops", "XLA Modules")
+HARNESS, PROGRAM = "sb.", "ss."   # prefixes of the harness's and the program's spans
 
 
 def op_name(text: str) -> str:
@@ -44,7 +47,7 @@ def load_xspace(trace_dir: str) -> Dict[str, Any]:
     if not paths:
         raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
     pd = ProfileData.from_file(paths[-1])
-    planes = []
+    planes, program = [], []
     for plane in pd.planes:
         device = plane.name.startswith("/device:TPU:")
         if not device and plane.name != "/host:CPU":
@@ -55,14 +58,16 @@ def load_xspace(trace_dir: str) -> Dict[str, Any]:
                 continue
             events = []
             for e in line.events:
-                if not device and not e.name.startswith("sb."):
+                if not device and e.name.startswith(PROGRAM):
+                    program.append([e.name, float(e.start_ns), float(e.duration_ns), {}])
+                if not device and not e.name.startswith(HARNESS):
                     continue
                 name = op_label(e.name) if device and line.name == "XLA Ops" else e.name
                 events.append([name, float(e.start_ns), float(e.duration_ns), {}])
             if events:
                 lines.append({"name": line.name, "events": events})
         planes.append({"name": plane.name, "lines": lines})
-    return {"planes": planes}
+    return {"planes": planes, "program": sorted(program, key=lambda e: e[1])}
 
 
 def union(intervals: List[Tuple[float, float]]) -> List[Tuple[float, float]]:
@@ -107,6 +112,16 @@ class Reduced:
             self.ops.append(ops)
         self.modules.sort()
         self._mod_starts = [m[0] for m in self.modules]
+        self.program: List[Tuple[float, float, str]] = [
+            (s, s + d, name) for name, s, d, _ in data.get("program", [])]
+        self._busy = []   # per device: interval starts, ends, busy ns before each
+        for dev in range(len(self.ops)):
+            iv = self.busy_intervals(dev)
+            before, acc = [], 0.0
+            for s, e in iv:
+                before.append(acc)
+                acc += e - s
+            self._busy.append(([s for s, _ in iv], [e for _, e in iv], before))
 
     # ------------------------------------------------------------ whole device
     @property
@@ -120,6 +135,29 @@ class Reduced:
     def busy_s(self) -> float:
         total = sum(e - s for dev in range(len(self.ops)) for s, e in self.busy_intervals(dev))
         return total / len(self.ops) / 1e9
+
+    def _busy_until(self, dev: int, t: float) -> float:
+        """Busy ns of device ``dev`` from the window's start to ``t``."""
+        starts, ends, before = self._busy[dev]
+        i = bisect.bisect_right(starts, t) - 1
+        return 0.0 if i < 0 else before[i] + min(t, ends[i]) - starts[i]
+
+    def idle_inside(self, lo: float, hi: float) -> float:
+        """Device idle seconds inside the host interval ``[lo, hi]`` (ns, on
+        the trace's clock), clipped to the window, averaged over the chips."""
+        lo, hi = max(lo, self.lo), min(hi, self.hi)
+        if hi <= lo:
+            return 0.0
+        idle = [(hi - lo) - (self._busy_until(d, hi) - self._busy_until(d, lo))
+                for d in range(len(self._busy))]
+        return sum(idle) / len(idle) / 1e9
+
+    # ------------------------------------------------------------ host spans
+    def spans_named(self, name: str) -> List[Tuple[float, float]]:
+        """``(start, end)`` in ns of the host spans (the harness's or the
+        program's) named ``name`` that start inside the window, in time order."""
+        return sorted((s, e) for s, e, n in self.spans + self.program
+                      if n == name and self.lo <= s < self.hi)
 
     # ------------------------------------------------------------ kernels
     def kernel_s(self, kernel: str, dev: int = 0) -> float:

@@ -11,7 +11,7 @@ engine is drained before the next rate.  The backlog grew where the
 least-squares slope of that count over the window, times the window, is
 more than ``max(2, 5% of the requests due in the window)``.  Prints one
 table row per rate and, last, a JSON object with the rows and the knee.
-The cell's ``rate_per_s`` is then set by hand to 0.8 of the knee.
+The cell's ``rate_per_s`` is then set by hand to 0.6 of the knee.
 """
 from __future__ import annotations
 
@@ -50,7 +50,6 @@ def main(argv=None) -> int:
 
     from sbench import harness, program, spec
     from sbench.traffic import make_plan
-    from sbench.weights import make_weights
 
     cell = spec.load_cell(args.workload)
     try:
@@ -60,7 +59,7 @@ def main(argv=None) -> int:
         return 2
     harness.enable_cache()
     cfg, mix = cell.config, cell.traffic
-    serve = program.build(cfg, make_weights(cfg, args.seed))
+    serve = program.build(cell.family, cfg, cell.family.make_weights(cfg, args.seed))
     serve.engine.warmup(max_prompt_len=int(mix["prompt"]["max"]))
     print(f"sweep {cell.name} on {dev}: set-up {time.perf_counter() - T_START:.1f}s",
           file=sys.stderr, flush=True)

@@ -7,9 +7,7 @@ import numpy as np
 import pytest
 
 from streambench_testlib import FIX, spec
-from reference import qwen
 from sbench import program
-from sbench.weights import make_weights
 
 
 @pytest.mark.parametrize("name", ["qwen3-tiny", "qwen2-tiny"])
@@ -17,9 +15,10 @@ def test_reference_matches_program_logits(name):
     from repro.models import build_model
 
     cfg = dict(spec.load_json(FIX / f"{name}.json"), torch_dtype="float32")
-    w = jax.tree.map(lambda x: x.astype(jnp.float32), make_weights(cfg, 2**31 + 5))
-    arch = program.arch_config(cfg)
-    params = program.program_params(cfg, arch, jax.tree.map(jnp.copy, w))
+    fam, qwen = spec.family(cfg), spec.reference(cfg)
+    w = jax.tree.map(lambda x: x.astype(jnp.float32), fam.make_weights(cfg, 2**31 + 5))
+    arch = fam.arch_config(cfg)
+    params = program.program_params(fam, cfg, arch, jax.tree.map(jnp.copy, w))
     toks = np.random.default_rng(0).integers(0, cfg["vocab_size"], 40).astype(np.int32)
     got = build_model(arch).forward(params, {"tokens": jnp.asarray(toks)[None]})[0]
     want = qwen.logits_at(cfg, w, np.pad(toks, (0, 24)), 0, 40)
@@ -32,7 +31,8 @@ def test_reference_matches_program_logits(name):
 
 def test_int8_control_differs_from_reference():
     cfg = spec.load_json(FIX / "qwen3-tiny.json")
-    w = make_weights(cfg, 3)
+    qwen = spec.reference(cfg)
+    w = spec.family(cfg).make_weights(cfg, 3)
     toks = np.arange(64, dtype=np.int32) % cfg["vocab_size"]
     ref = np.asarray(qwen.logits_at(cfg, w, toks, 0, 32))
     low = np.asarray(qwen.logits_at(cfg, w, toks, 0, 32, quant="int8"))

@@ -4,10 +4,12 @@ import json
 import re
 import shutil
 
+import jax
+import jax.numpy as jnp
 import pytest
 
 from streambench_testlib import BENCH, ROOT, spec
-from sbench.weights import Dims
+from sbench import program
 
 BM = spec.benchmark()
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -46,7 +48,13 @@ def test_every_cell_loads(cell):
     assert c.per_layer, "every cell reports a per-layer metric"
     for m in c.per_layer:
         assert callable(spec.metric_reader(m["name"]))
-    Dims.of(c.config)
+    fam = c.family
+    assert fam.kv_bytes_per_token(c.config) > 0
+    assert callable(c.reference.logits_at)
+    # the family's weights and the program's tree at full size, by shape only
+    w = jax.eval_shape(lambda: fam.make_weights(c.config, 2**31 + 1))
+    assert all(x.dtype == jnp.bfloat16 for x in jax.tree.leaves(w))
+    jax.eval_shape(lambda w: program.program_params(fam, c.config, fam.arch_config(c.config), w), w)
     assert c.config["source"].startswith("https://")
     if c.traffic["arrival"] == "poisson":
         assert c.data["rate_per_s"] > 0

@@ -1,7 +1,10 @@
 """The trace reduction against a small recorded trace: about 50 ms of a
 ``qwen3-1.7b`` run on one TPU v5e (one decode program with its host spans),
-kept as ``fixtures/trace_v5e.json``.  Each number is checked against a plain
-recount of the same events."""
+kept as ``fixtures/trace_v5e.json``, and a hand-built profile with the
+program's ``ss.*`` spans beside the harness's ``sb.*``.  Each number is
+checked against a plain recount of the same events."""
+from types import SimpleNamespace
+
 import numpy as np
 import pytest
 
@@ -78,3 +81,85 @@ def test_top_ops_leave_out_containers(red):
     assert top and all(not name.split("/", 1)[1].startswith("while") for name, _ in top)
     assert sum(v for _, v in top) <= red.busy_s * 1.0001
     assert [v for _, v in top] == sorted((v for _, v in top), reverse=True)
+
+
+# ---------------------------------------------------------------- program spans
+def _ev(name, start, dur):
+    return SimpleNamespace(name=name, start_ns=start, duration_ns=dur)
+
+
+def _profile():
+    """A ``ProfileData`` stand-in: two TPU planes, the host plane with the
+    harness's and the program's spans and a host event of neither, and a
+    plane the reduction does not read."""
+    def plane(name, lines):
+        return SimpleNamespace(name=name, lines=[SimpleNamespace(name=n, events=e)
+                                                 for n, e in lines])
+    ops0 = [_ev("%fusion.1 = bf16[4]{0} fusion(%a)", 1100, 100),
+            _ev("%copy.2 = bf16[4]{0} copy(%b)", 1150, 150),
+            _ev("%decode_attention.3 = bf16[4]{0} custom-call(%c)", 1500, 100),
+            _ev("%fusion.4 = bf16[4]{0} fusion(%d)", 1950, 150)]
+    return SimpleNamespace(planes=[
+        plane("/device:TPU:0", [("XLA Ops", ops0),
+                                ("XLA Modules", [_ev("jit__lane_decode(1)", 1100, 500)]),
+                                ("Steps", [_ev("0", 1000, 1000)])]),
+        plane("/device:TPU:1", [("XLA Ops", [_ev("%fusion.9 = f32[1]{0} fusion()", 1000, 1000)])]),
+        plane("/host:CPU", [
+            ("python3", [_ev("sb.window", 1000, 1000), _ev("ss.step", 1050, 350),
+                         _ev("ss.draft", 1460, 20), _ev("ss.step", 1450, 250),
+                         _ev("PjitFunction(_lane_decode)", 1060, 5), _ev("sb.step", 1040, 700)]),
+            ("worker", [_ev("ss.step", 900, 120), _ev("ss.step", 1900, 150)])]),
+        plane("/host:metadata", [("x", [_ev("ss.step", 0, 1)])]),
+    ])
+
+
+@pytest.fixture
+def hand(tmp_path, monkeypatch):
+    import jax.profiler
+
+    (tmp_path / "t.xplane.pb").write_bytes(b"")
+    monkeypatch.setattr(jax.profiler, "ProfileData",
+                        SimpleNamespace(from_file=lambda path: _profile()))
+    return tr.load_xspace(str(tmp_path))
+
+
+def test_load_xspace_keeps_program_spans_apart(hand):
+    host = [p for p in hand["planes"] if p["name"] == "/host:CPU"][0]
+    harness_spans = [e[0] for ln in host["lines"] for e in ln["events"]]
+    assert harness_spans == ["sb.window", "sb.step"]   # the sb.* list as before
+    assert [(n, s, d) for n, s, d, _ in hand["program"]] == [
+        ("ss.step", 900, 120), ("ss.step", 1050, 350), ("ss.step", 1450, 250),
+        ("ss.draft", 1460, 20), ("ss.step", 1900, 150)]
+    dev = [p for p in hand["planes"] if p["name"] == "/device:TPU:0"][0]
+    assert [ln["name"] for ln in dev["lines"]] == ["XLA Ops", "XLA Modules"]
+    assert dev["lines"][0]["events"][0][0] == "fusion.1 bf16[4]"
+    assert "/host:metadata" not in [p["name"] for p in hand["planes"]]
+
+
+def test_spans_named_and_idle_inside_hand_counts(hand):
+    red = tr.Reduced(hand)
+    # spans that start inside the window [1000, 2000), from either list
+    assert red.spans_named("ss.step") == [(1050, 1400), (1450, 1700), (1900, 2050)]
+    assert red.spans_named("ss.draft") == [(1460, 1480)]
+    assert red.spans_named("sb.step") == [(1040, 1740)]
+    assert red.spans_named("ss.nothing") == []
+    # device 0 is busy over [1100, 1300], [1500, 1600], [1950, 2000]; device 1 all along
+    assert red.idle_inside(1050, 1400) == pytest.approx((350 - 200) / 2 / 1e9)
+    assert red.idle_inside(1450, 1700) == pytest.approx((250 - 100) / 2 / 1e9)
+    assert red.idle_inside(1900, 2050) == pytest.approx((100 - 50) / 2 / 1e9)   # clipped
+    assert red.idle_inside(900, 1020) == pytest.approx(20 / 2 / 1e9)
+    assert red.idle_inside(1120, 1280) == 0.0
+    assert red.idle_inside(2100, 2200) == 0.0
+    total = red.idle_inside(red.lo, red.hi)
+    assert total == pytest.approx(red.window_s - red.busy_s)
+    # the harness's labels of idle gaps are as before: sb.* spans only
+    assert set(dict(red.idle_gaps())) <= {"none", "sb.step"}
+
+
+def test_idle_inside_agrees_with_brute_force_on_the_recorded_trace(red):
+    busy = _timeline(red)
+    lo = int(red.lo)
+    for a, b in [(red.lo, red.hi), (red.lo + 1e6, red.lo + 7e6), (red.lo + 3e7, red.lo + 3.3e7)]:
+        a_i, b_i = int(a) - lo, int(b) - lo
+        want = ((b_i - a_i) - busy[a_i:b_i].sum()) / 1e9
+        assert red.idle_inside(a, b) == pytest.approx(want, abs=2e-9 * len(red.ops[0]))
